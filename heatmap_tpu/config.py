@@ -92,8 +92,7 @@ class Config:
                                        # (the runtime warns at startup)
     emit_pull: str = "auto"            # "auto" | "full" | "prefix": prefix
                                        # pulls head row + live-rows bucket
-                                       # (2 transfers, far fewer bytes) —
-                                       # wins on remote-attached chips;
+                                       # (2 transfers, far fewer bytes);
                                        # auto = prefix off-CPU (single-
                                        # device paths; sharded pulls stay
                                        # full)
@@ -102,8 +101,7 @@ class Config:
                                        # emits of up to K batches stay on
                                        # device and are pulled in ONE
                                        # flush, amortizing the per-batch
-                                       # D2H round trip (ruinous on
-                                       # remote-attached chips).  Flush is
+                                       # D2H round trip.  Flush is
                                        # forced before checkpoints, on
                                        # idle polls, at close, and under
                                        # watermark/growth pressure, so

@@ -11,8 +11,7 @@ This class fuses all pairs into a single jitted step:
   * the H3 snap runs once per **unique resolution** (a 3-window config
     snaps once, not three times);
   * each pair's ``merge_batch`` fold runs inside the same XLA program, so
-    the per-step dispatch overhead (ruinous on remote-attached chips) is
-    paid once;
+    the per-step dispatch overhead is paid once;
   * the per-pair packed emits are stacked into one (P, E+1, 13) matrix —
     the whole batch's output crosses the device->host link in ONE pull.
 

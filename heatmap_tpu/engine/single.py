@@ -69,8 +69,8 @@ class SingleAggregator:
         """Single-transfer variant: returns (packed_emit_device, stats_device).
 
         The caller pulls the packed matrix with one device_get (see
-        engine.step.pack_emit) — the low-overhead path for remote-attached
-        devices; the bench hot loop uses it."""
+        engine.step.pack_emit) — one transfer per batch; the bench hot
+        loop uses it."""
         self.state, packed, stats = self._step_packed(
             self.state,
             jnp.asarray(lat_rad), jnp.asarray(lng_rad), jnp.asarray(speed),

@@ -21,7 +21,7 @@ Design notes (TPU-first):
   hex-plane rounding.  In float32 at res 9 the worst-case coordinate error is
   ~2e-3 grid units (~0.4 m on the ground), i.e. points within that distance
   of a cell edge may snap to the neighboring cell — far below GPS noise.
-  Pass ``dtype=jnp.float64`` (under ``jax.experimental.enable_x64``) for
+  Pass ``dtype=jnp.float64`` (under ``jax.enable_x64``) for
   bit-exact agreement with the host oracle (hexgrid.host).
 - All lookup tables are tiny (<3 KB) int32 gathers.
 

@@ -170,27 +170,20 @@ def latlng_to_cell_pallas(lat, lng, res: int, interpret: bool = False):
 
 @functools.lru_cache(maxsize=1)
 def pallas_available() -> bool:
-    """True when the kernel compiles on the current default backend
-    (probed once; engine._snap_impl uses this to fall back to XLA).
+    """True when the default backend is a TPU and the kernel lowers
+    there; False on any other backend.  On a TPU a lowering error
+    RAISES: a kernel that should compile and does not is a fault to
+    see, never a reason to run the XLA snap in its place.
 
-    The probe must work at trace time (engine._snap_impl runs inside the
-    engine's jit) yet actually LOWER the kernel — under an ambient trace
-    a plain jitted call is traced, not compiled, so no Mosaic error
-    would surface.  AOT ``lower().compile()`` on abstract shapes does
-    both: it opens a fresh trace independent of any ambient tracer and
-    runs the real backend compile.  The previous probe forced eagerness
-    with ``jax.ensure_compile_time_eval()`` instead, which made every
-    no-tracer-input op inside the kernel trace (``jnp.zeros``, np-scalar
-    wraps) evaluate to a CONCRETE array that pallas then rejected as a
-    captured constant — the probe returned False on the very v5e where
-    the kernel lowers and wins 2.6-3.1x (HW_PROGRESS ``pallas_lowers``
-    banked ok because that unit jits normally), silently degrading the
-    banked "pallas" policy to XLA on hardware.
-    """
-    try:
-        spec = jax.ShapeDtypeStruct((_LANES * _SUBLANES,), jnp.float32)
-        jax.jit(functools.partial(
-            latlng_to_cell_pallas, res=8)).lower(spec, spec).compile()
-        return True
-    except Exception:  # Mosaic lowering / platform errors
+    The probe must work at trace time (engine._snap_impl runs inside
+    the engine's jit) yet actually LOWER the kernel — under an ambient
+    trace a plain jitted call is traced, not compiled, so no Mosaic
+    error would surface.  AOT ``lower().compile()`` on abstract shapes
+    does both: it opens a fresh trace independent of any ambient tracer
+    and runs the real backend compile."""
+    if jax.default_backend() != "tpu":
         return False
+    spec = jax.ShapeDtypeStruct((_LANES * _SUBLANES,), jnp.float32)
+    jax.jit(functools.partial(
+        latlng_to_cell_pallas, res=8)).lower(spec, spec).compile()
+    return True

@@ -74,6 +74,40 @@ _V_MOVE = 3.0              # m/s: must exceed once before stop can alarm
 _MAX_ANOMALY_BUFFER = 65536
 
 
+class VelocityView:
+    """Warm entities' positions and filtered velocities at one fold
+    (copies, so later folds leave it as it was); ``field(res)`` is the
+    per-cell mean velocity, computed once per resolution."""
+
+    def __init__(self, snap, lat_rad, lng_rad, vn, ve):
+        self._snap = snap
+        self._lat, self._lng = lat_rad, lng_rad
+        self._vn, self._ve = vn, ve
+        self._fields: dict = {}
+
+    def field(self, res: int) -> dict:
+        hit = self._fields.get(res)
+        if hit is not None:
+            return hit
+        out: dict = {}
+        if len(self._lat):
+            cells = self._snap(self._lat, self._lng, res)
+            order = np.argsort(cells, kind="stable")
+            cells = cells[order]
+            vn = self._vn[order].astype(np.float64)
+            ve = self._ve[order].astype(np.float64)
+            bnd = np.flatnonzero(np.concatenate(
+                ([True], cells[1:] != cells[:-1])))
+            counts = np.diff(np.append(bnd, len(cells)))
+            sve = np.add.reduceat(ve, bnd)
+            svn = np.add.reduceat(vn, bnd)
+            for c, se, sn, ct in zip(cells[bnd], sve, svn, counts):
+                out[int(c)] = (float(se / ct * 3.6),
+                               float(sn / ct * 3.6), int(ct))
+        self._fields[res] = out
+        return out
+
+
 class InferenceEngine:
     """Per-entity streaming filter + anomaly/forecast policy."""
 
@@ -115,7 +149,7 @@ class InferenceEngine:
         self._events = 0
         self._last_fold_ms = 0.0
         self._last_wall = 0.0
-        self._vel_cache: dict = {}
+        self._view: VelocityView | None = None  # this fold's, lazily
         self._tbl_last = {k: 0 for k in (
             "n_seeded", "n_evicted_ttl", "n_evicted_lru",
             "n_reseed_handoff", "n_reseed_teleport")}
@@ -180,7 +214,7 @@ class InferenceEngine:
             self._fold_locked(cols)
             self._folds += 1
             self._events += n
-            self._vel_cache.clear()
+            self._view = None
         self._last_wall = ts_wall if ts_wall is not None else self.clock()
         dt = time.perf_counter() - t0
         self._last_fold_ms = dt * 1e3
@@ -486,32 +520,24 @@ class InferenceEngine:
         """{cell(uint64): (vx_east_kmh, vy_north_kmh, n_entities)} —
         mean filtered velocity of warm tracked entities per cell at
         ``res``.  A pure function of the table (cached per fold)."""
+        return self.velocity_view().field(res)
+
+    def velocity_view(self) -> "VelocityView":
+        """The warm entities' filtered state as of the latest fold,
+        frozen: its ``field(res)`` is what :meth:`velocity_field` returns
+        now, whenever it is called.  The runtime takes one per dispatched
+        batch, so a tile's velocity columns are those of the batch that
+        emitted it, not of whenever its emit ring was pulled."""
         with self._lock:
-            key = (res, self._folds)
-            hit = self._vel_cache.get(key)
-            if hit is not None:
-                return hit
-            occ = np.nonzero((self.table.vid >= 0)
-                             & (self.table.n_upd >= 2))[0]
-            out: dict = {}
-            if len(occ):
-                lat, lng = latlng_of(self.table.x[occ],
-                                     self.table.ref[occ])
-                cells = self._snap(np.deg2rad(lat), np.deg2rad(lng), res)
-                order = np.argsort(cells, kind="stable")
-                cells = cells[order]
-                vn = self.table.x[occ][order, 2].astype(np.float64)
-                ve = self.table.x[occ][order, 3].astype(np.float64)
-                bnd = np.flatnonzero(np.concatenate(
-                    ([True], cells[1:] != cells[:-1])))
-                counts = np.diff(np.append(bnd, len(cells)))
-                sve = np.add.reduceat(ve, bnd)
-                svn = np.add.reduceat(vn, bnd)
-                for c, se, sn, ct in zip(cells[bnd], sve, svn, counts):
-                    out[int(c)] = (float(se / ct * 3.6),
-                                   float(sn / ct * 3.6), int(ct))
-            self._vel_cache[key] = out
-            return out
+            if self._view is None:
+                occ = np.nonzero((self.table.vid >= 0)
+                                 & (self.table.n_upd >= 2))[0]
+                x = self.table.x[occ]
+                lat, lng = latlng_of(x, self.table.ref[occ])
+                self._view = VelocityView(
+                    self._snap, np.deg2rad(lat), np.deg2rad(lng),
+                    x[:, 2], x[:, 3])
+            return self._view
 
     def forecast_cells(self, h_s: float, res: int) -> dict:
         """{cell(uint64): predicted_entity_count} after advecting every
@@ -558,7 +584,7 @@ class InferenceEngine:
             m = self.table.restore(
                 data, intern_v if intern_v is not None else {},
                 n_part=self.n_part)
-            self._vel_cache.clear()
+            self._view = None
             return m
 
     # ----------------------------------------------------------- observe

@@ -11,16 +11,11 @@ import argparse
 import logging
 import time
 
-# pin CPU if the accelerator link is dead — the stream import below
-# touches jax at module level and would otherwise hang forever
-from heatmap_tpu.utils.device_probe import ensure_reachable_backend
-
-ensure_reachable_backend()
-
-from heatmap_tpu.config import load_config  # noqa: E402
-from heatmap_tpu.serve import start_background  # noqa: E402
-from heatmap_tpu.sink import MemoryStore  # noqa: E402
-from heatmap_tpu.stream import MicroBatchRuntime, SyntheticSource  # noqa: E402
+from heatmap_tpu.config import load_config
+from heatmap_tpu.serve import start_background
+from heatmap_tpu.sink import MemoryStore
+from heatmap_tpu.stream import MicroBatchRuntime, SyntheticSource
+from heatmap_tpu.utils.jaxenv import enable_compile_cache, require_accelerator
 
 log = logging.getLogger("demo")
 
@@ -37,6 +32,8 @@ def main(argv=None):
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(levelname)s %(name)s %(message)s")
+    enable_compile_cache()
+    require_accelerator()
     cfg = load_config(
         {}, batch_size=args.batch, store="memory",
         checkpoint_dir=f"/tmp/heatmap-demo-ckpt-{int(time.time())}",

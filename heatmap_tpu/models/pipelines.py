@@ -130,3 +130,36 @@ def get_pipeline(name: str) -> Pipeline:
     if name not in PIPELINES:
         raise KeyError(f"unknown pipeline {name!r}; have {sorted(PIPELINES)}")
     return PIPELINES[name]
+
+
+def build_runtime(p: Pipeline, source: Source | None = None,
+                  n_devices: int | None = None):
+    """The streaming job's wiring (``python -m heatmap_tpu.stream``):
+    store, source and ``MicroBatchRuntime`` for pipeline ``p``.  Returns
+    ``(runtime, store)``; the caller runs the runtime and closes the
+    store.
+
+    ``source`` replaces the pipeline's own source factory.  Devices:
+    ``n_devices=None`` takes the pipeline's ``num_shards`` or, when that
+    is 0, every visible device (a mesh once there are several, or
+    several hosts); ``n_devices=1`` pins the run to ``jax.devices()[0]``;
+    ``n_devices=N`` builds an N-device mesh.  Raises when JAX found no
+    accelerator and ``JAX_PLATFORMS=cpu`` did not choose the CPU."""
+    import jax
+
+    from heatmap_tpu.parallel import make_mesh, multihost
+    from heatmap_tpu.sink import make_store
+    from heatmap_tpu.stream import MicroBatchRuntime
+    from heatmap_tpu.utils.jaxenv import require_accelerator
+
+    # HEATMAP_COORDINATOR et al. start the cross-host runtime, which
+    # must precede the first backend touch (the check below)
+    multihost.init_from_env()
+    require_accelerator()
+    n = n_devices or p.config.num_shards or len(jax.devices())
+    mesh = None
+    if n > 1 or (n_devices is None and jax.process_count() > 1):
+        mesh = make_mesh(n_devices or p.config.num_shards or None)
+    store = make_store(p.config)
+    src = source if source is not None else p.make_source(p.config)
+    return MicroBatchRuntime(p.config, src, store, mesh=mesh), store

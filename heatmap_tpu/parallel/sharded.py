@@ -33,14 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental after this environment's
-# jax; bind whichever exists (identical signature for the kwargs used
-# here: f, mesh, in_specs, out_specs)
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from heatmap_tpu.parallel import multihost
 from heatmap_tpu.engine.state import (
     EMPTY_KEY_HI,
@@ -340,6 +332,51 @@ def exchange_lane_capacity(n_local: int, n_shards: int,
     return max(1, int(math.ceil(m + z * math.sqrt(m) + z * z)))
 
 
+def _step_specs(n_pairs: int):
+    """shard_map partition specs of the sharded step: (per-pair state
+    specs, step input specs, one pair's emit specs, one pair's stats
+    specs)."""
+    spec1 = P(AXIS)
+    spec2 = P(AXIS, None)
+    state_specs = TileState(
+        key_hi=spec1, key_lo=spec1, key_ws=spec1, count=spec1,
+        sum_speed=spec1, sum_speed2=spec1, sum_lat=spec1, sum_lon=spec1,
+        hist=spec2, anchor_speed=spec1, anchor_lat=spec1,
+        anchor_lon=spec1, comp=spec2,
+    )
+    emit_specs = BatchEmit(
+        key_hi=spec1, key_lo=spec1, key_ws=spec1, count=spec1,
+        sum_speed=spec1, sum_speed2=spec1, sum_lat=spec1, sum_lon=spec1,
+        anchor_speed=spec1, anchor_lat=spec1, anchor_lon=spec1,
+        hist=spec2, valid=spec1, n_emitted=P(AXIS), overflowed=P(AXIS),
+    )
+    stats_specs = ShardStats(*([P()] * 7))
+    states_specs = tuple([state_specs] * n_pairs)
+    in_specs = (states_specs, spec1, spec1, spec1, spec1, spec1, P())
+    return states_specs, in_specs, emit_specs, stats_specs
+
+
+def packed_step(mesh: Mesh, params_list, bucket_cap: int):
+    """The streaming hot path's sharded program: fold one global batch
+    into every pair's slab and return the stacked packed emits.
+    ``params_list`` is read when the program traces (a list that
+    ``ShardedAggregator.grow`` mutates in place)."""
+    n_pairs = len(params_list)
+    states_specs, in_specs, _, _ = _step_specs(n_pairs)
+    body = functools.partial(_sharded_step_body, params_list,
+                             mesh.devices.size, bucket_cap)
+
+    def body_packed(*a):
+        states, emits, packed, stats = body(*a)
+        return states, packed
+
+    return jax.jit(
+        jax.shard_map(body_packed, mesh=mesh, in_specs=in_specs,
+                      out_specs=(states_specs, P(AXIS, None))),
+        donate_argnums=donate_state_argnums(),
+    )
+
+
 class ShardedAggregator:
     """Host-facing wrapper owning the sharded device state.
 
@@ -400,24 +437,10 @@ class ShardedAggregator:
             _sharded_step_body, self.params_list, self.n_shards,
             self.bucket_cap,
         )
-        spec1 = P(AXIS)
-        spec2 = P(AXIS, None)
-        state_specs = TileState(
-            key_hi=spec1, key_lo=spec1, key_ws=spec1, count=spec1,
-            sum_speed=spec1, sum_speed2=spec1, sum_lat=spec1, sum_lon=spec1,
-            hist=spec2, anchor_speed=spec1, anchor_lat=spec1,
-            anchor_lon=spec1, comp=spec2,
-        )
-        emit_specs = BatchEmit(
-            key_hi=spec1, key_lo=spec1, key_ws=spec1, count=spec1,
-            sum_speed=spec1, sum_speed2=spec1, sum_lat=spec1, sum_lon=spec1,
-            anchor_speed=spec1, anchor_lat=spec1, anchor_lon=spec1,
-            hist=spec2, valid=spec1, n_emitted=P(AXIS), overflowed=P(AXIS),
-        )
-        stats_specs = ShardStats(*([P()] * 7))
         n_pairs = len(self.params_list)
-        states_specs = tuple([state_specs] * n_pairs)
-        in_specs = (states_specs, spec1, spec1, spec1, spec1, spec1, P())
+        states_specs, in_specs, emit_specs, stats_specs = _step_specs(
+            n_pairs)
+        spec1, spec2 = P(AXIS), P(AXIS, None)
         # two lazily-compiled variants of the SAME body, each returning
         # only what its caller consumes (jit cannot DCE returned outputs;
         # the streaming hot path must not materialize the emit pytrees)
@@ -426,23 +449,16 @@ class ShardedAggregator:
             states, emits, packed, stats = body(*a)
             return states, emits, stats
 
-        def body_packed(*a):
-            states, emits, packed, stats = body(*a)
-            return states, packed
-
         self._step = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 body_full, mesh=mesh, in_specs=in_specs,
                 out_specs=(states_specs, tuple([emit_specs] * n_pairs),
                            tuple([stats_specs] * n_pairs)),
             ),
             donate_argnums=donate_state_argnums(),  # fold slabs in place
         )
-        self._step_packed = jax.jit(
-            _shard_map(body_packed, mesh=mesh, in_specs=in_specs,
-                          out_specs=(states_specs, spec2)),
-            donate_argnums=donate_state_argnums(),
-        )
+        self._step_packed = packed_step(mesh, self.params_list,
+                                        self.bucket_cap)
 
         # prekeys variant: host-precomputed (hi, lo) planes per unique
         # resolution ride as extra sharded args (HEATMAP_H3_IMPL=native)
@@ -460,7 +476,7 @@ class ShardedAggregator:
 
         in_specs_pre = in_specs + tuple([spec1] * (2 * len(uniq_res)))
         self._step_packed_pre = jax.jit(
-            _shard_map(body_packed_pre, mesh=mesh, in_specs=in_specs_pre,
+            jax.shard_map(body_packed_pre, mesh=mesh, in_specs=in_specs_pre,
                           out_specs=(states_specs, spec2)),
             donate_argnums=donate_state_argnums(),
         )

@@ -139,7 +139,9 @@ def main(argv=None) -> int:
 
     from heatmap_tpu.serve.api import serve_forever
     from heatmap_tpu.sink import make_store
+    from heatmap_tpu.utils.jaxenv import enable_compile_cache
 
+    enable_compile_cache()
     log.info("serve core: %s", cfg.serve_core)
 
     # read-side: under a sharded jsonl config, load the union of every

@@ -3113,7 +3113,8 @@ class _QuietHandler(WSGIRequestHandler):
 def _make_http_server(store, cfg, runtime, host, port,
                       reuse_port: bool = False):
     host = host or (getattr(cfg, "serve_host", None) or "127.0.0.1")
-    port = port if port is not None else (getattr(cfg, "serve_port", None) or 5000)
+    # serve_port 0 asks for an ephemeral port, as the serve CLI does
+    port = port if port is not None else getattr(cfg, "serve_port", 5000)
     app = make_wsgi_app(store, cfg, runtime)
     core = getattr(cfg, "serve_core", None) or "thread"
     if core == "epoll":

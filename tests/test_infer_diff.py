@@ -96,10 +96,10 @@ def mk_stream(late=True):
 
 def run_rt(tmp_path, events, store, tag, reducers=("count",), view=None,
            batch=BATCH, shards=1, index=0, entity_shards=0,
-           checkpoint_every=0, source=None, run=True):
+           checkpoint_every=0, source=None, run=True, flush_k=3):
     cfg = load_config(
         {}, batch_size=batch, state_capacity_log2=12, speed_hist_bins=8,
-        store="memory", emit_flush_k=3, reducers=reducers,
+        store="memory", emit_flush_k=flush_k, reducers=reducers,
         shards=shards, shard_index=index, entity_shards=entity_shards,
         checkpoint_dir=str(tmp_path / f"ckpt-{tag}"))
     if source is None:
@@ -189,6 +189,22 @@ def test_count_path_byte_identity_reducers_on_vs_off(tmp_path):
     # the hazard corpus did exercise the filter: anomalies flowed
     assert not off_view.captured_anomalies
     assert _anoms_of(on_view)
+
+
+@pytest.mark.parametrize("flush_k", [1, 8])
+def test_velocity_columns_follow_the_batch_not_the_flush(tmp_path, flush_k):
+    """A tile's velocity columns are the filter's as of the batch that
+    emitted the tile, so how long its emit ring parked the batch (the
+    per-device cadence of a mesh, emit_flush_k, the governor) leaves
+    every doc byte-identical."""
+    events = mk_stream()
+    base, other = MemoryStore(), MemoryStore()
+    run_rt(tmp_path, events, base, "k3", reducers=("count", "kalman"))
+    run_rt(tmp_path, events, other, f"k{flush_k}",
+           reducers=("count", "kalman"), flush_k=flush_k)
+    assert any(any(vk in d for vk in _VEL_KEYS)
+               for d in base._tiles.values())
+    assert base._tiles == other._tiles
 
 
 # --------------------------------------------- re-batching determinism

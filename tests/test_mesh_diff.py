@@ -30,6 +30,7 @@ import copy
 import time
 
 import numpy as np
+import pytest
 
 from heatmap_tpu.config import load_config
 from heatmap_tpu.parallel import make_mesh
@@ -365,3 +366,59 @@ def test_mesh_partition_stability_and_composition():
     mp = MeshPartition(2, snap_res=8, outer_shards=2)
     dev = mp.device_of_cells(cells[owned])
     assert len(set(dev.tolist())) == 2, "quotient bits decorrelate"
+
+
+@pytest.mark.parametrize("partition", ["mesh", "shards"])
+def test_partitioned_stream_refuses_in_program_snap(tmp_path, monkeypatch,
+                                                    partition):
+    """Rows are routed by their host-snapped cell, so a partitioned
+    stream must group by that same cell.  Grouped by the f32 in-program
+    snap, an edge point opens its (cell, window) group on a second
+    owner and the store keeps one partial group: on a v5e mesh 2.65% of
+    the synthetic_backfill events went missing that way (PR 21).  An
+    explicit in-program snap on a partitioned stream is refused."""
+    from heatmap_tpu.engine import step as engine_step
+
+    monkeypatch.setattr(engine_step, "SNAP_IMPL", None)
+    monkeypatch.setenv("HEATMAP_H3_IMPL", "xla")
+    cfg = load_config(
+        {}, batch_size=BATCH, state_capacity_log2=12, store="memory",
+        checkpoint_dir=str(tmp_path / "ckpt"),
+        **({"shards": 2, "shard_index": 0} if partition == "shards"
+           else {}))
+    mesh = make_mesh(N_DEV) if partition == "mesh" else None
+    with pytest.raises(RuntimeError, match="partitioned"):
+        MicroBatchRuntime(cfg, MemorySource([]), MemoryStore(), mesh=mesh)
+
+
+def test_partitioned_resume_refuses_in_program_keyed_checkpoint(
+        tmp_path, monkeypatch):
+    """Before PR 21 a partitioned mesh off the CPU keyed its checkpoints
+    with the in-program snap ("xla").  Resuming such a checkpoint under
+    HEATMAP_H3_IMPL=auto would pin the in-program snap again and bring
+    back the grouping that lost events: the resume raises instead."""
+    import glob
+    import json
+
+    from heatmap_tpu.engine import step as engine_step
+
+    # the pin writes the process-wide snap policy: restore it after
+    monkeypatch.setattr(engine_step, "SNAP_IMPL", None)
+    monkeypatch.setenv("HEATMAP_H3_IMPL", "auto")
+    events = mk_stream()
+    mesh = make_mesh(N_DEV)
+    run_one(tmp_path, events, "xla-keyed", mesh=mesh, checkpoint_every=1,
+            max_batches=2)
+    metas = glob.glob(str(tmp_path / "ckpt-xla-keyed" / "commit-*"
+                          / "meta.json"))
+    assert metas
+    for path in metas:
+        with open(path) as fh:
+            meta = json.load(fh)
+        assert meta["snap_impl"] == "native"
+        meta["snap_impl"] = "xla"
+        with open(path, "w") as fh:
+            json.dump(meta, fh)
+    with pytest.raises(RuntimeError, match="partitioned"):
+        run_one(tmp_path, events, "xla-keyed", mesh=mesh,
+                checkpoint_every=1)

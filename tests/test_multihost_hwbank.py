@@ -1,5 +1,5 @@
 """Multihost bank-skew agreement: two REAL processes whose local
-HW_PROGRESS banks disagree must converge on the same trace-time choices
+hardware banks (HEATMAP_HW_BANK) disagree must converge on the same trace-time choices
 (r5: hwbank measured-winner defaults).  A skewed checkout would
 otherwise compile DIFFERENT lockstep programs per host (divergent merge
 impls) or key f32 cell-edge events per ingesting host (divergent

@@ -64,7 +64,7 @@ def test_restart_budget_exhausts(tmp_path):
 
 def test_stall_detected_and_killed(tmp_path):
     """A child that starts its beacon then wedges (sleeps forever, like a
-    device op whose tunnel died) must be killed and restarted; the
+    device op that never returns) must be killed and restarted; the
     second launch exits 0 immediately."""
     log = tmp_path / "launches"
     body = """
@@ -116,22 +116,30 @@ sys.exit(0)
     assert sum(1 for _ in open(log)) >= 2
 
 
+def test_failover_is_off_by_default():
+    """The CPU failover is opt-in: a default policy, and one built from
+    an environment that does not name HEATMAP_SUPERVISE_FAILOVER_AFTER,
+    insist on the accelerator."""
+    assert RestartPolicy().failover_after is None
+    assert RestartPolicy.from_env({}).failover_after is None
+
+
 def test_failover_sets_platform(tmp_path):
     """After failover_after consecutive failures the child env gains
-    HEATMAP_PLATFORM=<failover_platform>; the child proves it by
+    JAX_PLATFORMS=<failover_platform>; the child proves it by
     succeeding only once it sees the override."""
     log = tmp_path / "launches"
     body = """
 import os, sys
 with open(os.environ["LAUNCH_LOG"], "a") as fh:
-    fh.write(os.environ.get("HEATMAP_PLATFORM", "-") + "\\n")
-sys.exit(0 if os.environ.get("HEATMAP_PLATFORM") == "cpu" else 1)
+    fh.write(os.environ.get("JAX_PLATFORMS", "-") + "\\n")
+sys.exit(0 if os.environ.get("JAX_PLATFORMS") == "cpu" else 1)
 """
     sup = Supervisor(
         _child(body),
         RestartPolicy(max_restarts=5, failover_after=2, **FAST),
         env={**{k: v for k, v in os.environ.items()
-                if k != "HEATMAP_PLATFORM"}, "LAUNCH_LOG": str(log)},
+                if k != "JAX_PLATFORMS"}, "LAUNCH_LOG": str(log)},
         heartbeat_path=str(tmp_path / "hb"), poll_s=0.02)
     assert sup.run() == 0
     launches = open(log).read().split()
@@ -168,7 +176,7 @@ def test_healthy_run_resets_failover_streak(tmp_path):
     body = """
 import os, sys, time
 with open(os.environ["LAUNCH_LOG"], "a") as fh:
-    fh.write(os.environ.get("HEATMAP_PLATFORM", "-") + "\\n")
+    fh.write(os.environ.get("JAX_PLATFORMS", "-") + "\\n")
 n = sum(1 for _ in open(os.environ["LAUNCH_LOG"]))
 time.sleep(1.0)   # healthy past the (tiny) budget window
 sys.exit(0 if n >= 3 else 1)
@@ -178,7 +186,7 @@ sys.exit(0 if n >= 3 else 1)
         RestartPolicy(max_restarts=10, window_s=0.3, failover_after=2,
                       backoff_s=0.05, backoff_max_s=0.1, term_grace_s=1.0),
         env={**{k: v for k, v in os.environ.items()
-                if k != "HEATMAP_PLATFORM"}, "LAUNCH_LOG": str(log)},
+                if k != "JAX_PLATFORMS"}, "LAUNCH_LOG": str(log)},
         heartbeat_path=str(tmp_path / "hb"), poll_s=0.02)
     assert sup.run() == 0
     assert not sup.failed_over
@@ -193,8 +201,8 @@ def test_wedged_child_still_trips_failover(tmp_path):
     body = """
 import os, sys, time
 with open(os.environ["LAUNCH_LOG"], "a") as fh:
-    fh.write(os.environ.get("HEATMAP_PLATFORM", "-") + "\\n")
-if os.environ.get("HEATMAP_PLATFORM") == "cpu":
+    fh.write(os.environ.get("JAX_PLATFORMS", "-") + "\\n")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
     sys.exit(0)
 time.sleep(3600)   # wedged before any beacon
 """
@@ -205,7 +213,7 @@ time.sleep(3600)   # wedged before any beacon
                       failover_after=2, backoff_s=0.05,
                       backoff_max_s=0.1, term_grace_s=1.0),
         env={**{k: v for k, v in os.environ.items()
-                if k != "HEATMAP_PLATFORM"}, "LAUNCH_LOG": str(log)},
+                if k != "JAX_PLATFORMS"}, "LAUNCH_LOG": str(log)},
         heartbeat_path=str(tmp_path / "hb"), poll_s=0.02)
     assert sup.run() == 0
     assert sup.failed_over

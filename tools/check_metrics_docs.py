@@ -237,7 +237,7 @@ def _smoke_quality():
 
 
 def main() -> int:
-    os.environ.setdefault("HEATMAP_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # the mesh smoke needs >= 2 devices; force 2 CPU host devices
     # BEFORE any backend initializes (lazy init — the first smoke below
     # is the first jax touch)

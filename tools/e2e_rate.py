@@ -22,7 +22,7 @@ where a batch's time goes.  Reference pipeline being matched:
 the driver loop — here they overlap the next batch's device step).
 
 Usage:
-    HEATMAP_PLATFORM=cpu python tools/e2e_rate.py --events 2000000
+    JAX_PLATFORMS=cpu python tools/e2e_rate.py --events 2000000
     python tools/e2e_rate.py --store memory        # sink-free ceiling
 """
 

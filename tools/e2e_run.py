@@ -71,12 +71,13 @@ def main() -> int:
 
 
 def _run(args, log, lines) -> int:
-    # pin CPU when the accelerator link is dead: jax.devices() below (and
-    # the engine import behind the runtime) would otherwise hang forever
-    from heatmap_tpu.utils.device_probe import ensure_reachable_backend
-
-    ensure_reachable_backend()
     import jax
+
+    from heatmap_tpu.utils.jaxenv import (enable_compile_cache,
+                                          require_accelerator)
+
+    enable_compile_cache()
+    require_accelerator()
 
     bootstrap = os.environ.get("KAFKA_BOOTSTRAP", "127.0.0.1:9092")
     mongo_uri = os.environ.get("MONGO_URI", "mongodb://127.0.0.1:27017")
