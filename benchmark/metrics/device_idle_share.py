@@ -1,0 +1,9 @@
+"""Device: per cent of the traced window in which no operation ran on
+the device (mean over the cell's devices)."""
+
+
+def read(run):
+    t = run.trace
+    if not t or t.get("window_s", 0) <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
