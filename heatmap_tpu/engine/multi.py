@@ -22,7 +22,6 @@ reference parity: heatmap_stream.py:112-133 run once per configuration).
 
 from __future__ import annotations
 
-import time
 from typing import NamedTuple, Sequence
 
 import jax
@@ -51,6 +50,11 @@ def fused_fold(params_list, states, lat_rad, lng_rad, speed, ts, valid,
     the production fusion.  Returns (new_states, [(emit, stats)] in pair
     order).
 
+    Each stage runs under a ``jax.named_scope`` (``snap_r<res>`` per
+    unique resolution, ``merge_r<res>_w<minutes>`` per pair), so a
+    device trace attributes every op of the step to one of them.
+    Scopes are trace-time metadata: the computation is unchanged.
+
     ``prekeys``: optional dict res -> (hi, lo) of PRE-COMPUTED cell keys
     (the host C++ snap, hexgrid.native_snap) — the fold then runs no
     in-program snap for those resolutions, only the valid-mask.  This is
@@ -65,19 +69,23 @@ def fused_fold(params_list, states, lat_rad, lng_rad, speed, ts, valid,
     by_res: dict[int, tuple] = {}
     for p in params_list:
         if p.res not in by_res:
-            if prekeys is not None and p.res in prekeys:
-                hi, lo = prekeys[p.res]
-                hi = jnp.where(valid, hi, jnp.uint32(EMPTY_KEY_HI))
-                lo = jnp.where(valid, lo, jnp.uint32(EMPTY_KEY_LO))
-            else:
-                hi, lo, _ = snap_and_window(lat_rad, lng_rad, ts, valid, p)
+            with jax.named_scope(f"snap_r{p.res}"):
+                if prekeys is not None and p.res in prekeys:
+                    hi, lo = prekeys[p.res]
+                    hi = jnp.where(valid, hi, jnp.uint32(EMPTY_KEY_HI))
+                    lo = jnp.where(valid, lo, jnp.uint32(EMPTY_KEY_LO))
+                else:
+                    hi, lo, _ = snap_and_window(lat_rad, lng_rad, ts, valid,
+                                                p)
             by_res[p.res] = (hi, lo)
     new_states, folded = [], []
     for p, st in zip(params_list, states):
         hi, lo = by_res[p.res]
-        ws = window_start(ts, valid, p.window_s)
-        st2, emit, stats = merge_batch(
-            st, hi, lo, ws, speed, lat_deg, lon_deg, ts, valid, cutoff, p)
+        with jax.named_scope(f"merge_r{p.res}_w{p.window_s // 60}"):
+            ws = window_start(ts, valid, p.window_s)
+            st2, emit, stats = merge_batch(
+                st, hi, lo, ws, speed, lat_deg, lon_deg, ts, valid, cutoff,
+                p)
         new_states.append(st2)
         folded.append((emit, stats))
     return tuple(new_states), folded
@@ -126,25 +134,22 @@ class MultiAggregator:
                         for leaf in init_state(capacity, hist_bins)])
             for _ in self.pairs
         ]
-        # host wall spent in step dispatch, per local shard (one entry
-        # here: the fused single-device program).  The dispatch is async,
-        # so this clocks trace+enqueue, not device execution — the
-        # runtime's "pull" span is where a slow device shows up; a
-        # growing dispatch clock means retraces or host-side stalls.
-        # Read by stream.runtime's callback gauges at /metrics scrapes.
-        self.device_seconds = [0.0]
         self.n_steps = 0
 
         param_list = self.params
 
+        def _pack(folded):
+            # ride the step stats in the packed head row, so the host
+            # needs NO second transfer for them (see stats_from_packed)
+            with jax.named_scope("pack"):
+                return jnp.stack([
+                    ride_stats(pack_emit(emit, p.speed_hist_max), stats)
+                    for p, (emit, stats) in zip(param_list, folded)])
+
         def _step(states, lat, lng, speed, ts, valid, cutoff):
             new_states, folded = fused_fold(param_list, states, lat, lng,
                                             speed, ts, valid, cutoff)
-            # ride the step stats in the packed head row, so the host
-            # needs NO second transfer for them (see stats_from_packed)
-            packs = [ride_stats(pack_emit(emit, p.speed_hist_max), stats)
-                     for p, (emit, stats) in zip(param_list, folded)]
-            return new_states, jnp.stack(packs)
+            return new_states, _pack(folded)
 
         self._step = jax.jit(_step,
                      donate_argnums=donate_state_argnums())
@@ -157,9 +162,7 @@ class MultiAggregator:
             new_states, folded = fused_fold(param_list, states, lat, lng,
                                             speed, ts, valid, cutoff,
                                             prekeys=prekeys)
-            packs = [ride_stats(pack_emit(emit, p.speed_hist_max), stats)
-                     for p, (emit, stats) in zip(param_list, folded)]
-            return new_states, jnp.stack(packs)
+            return new_states, _pack(folded)
 
         self._step_pre = jax.jit(
             _step_pre, donate_argnums=donate_state_argnums())
@@ -196,7 +199,6 @@ class MultiAggregator:
         when prekeys is given (a partial dict raises) — the pre-jitted
         _step_pre signature takes the full key tuple.
         """
-        t0 = time.monotonic()
         if prekeys is not None:
             missing = [r for r in self._uniq_res if r not in prekeys]
             if missing:
@@ -218,7 +220,6 @@ class MultiAggregator:
                 jnp.int32(watermark_cutoff),
             )
         self.states = list(states)
-        self.device_seconds[0] += time.monotonic() - t0
         self.n_steps += 1
         return packed
 

@@ -716,13 +716,17 @@ def _merge_fastpath(
     it) — and tier 2 feeds `_apply_routing` itself with rank-identical
     routing tables.  Differential-tested per batch
     (tests/test_merge_fastpath.py).  The slab's sorted invariant is
-    preserved by every tier."""
+    preserved by every tier.
+
+    The predicate runs under the named scope ``probe`` and the tiers
+    under ``tier_fast``, ``tier_insert`` and ``tier_slow``."""
     C = state.capacity
     N = ev_hi.shape[0]
     M = max(1024, N // 16)  # miss-event budget for the insert tier
-    (late, ev_valid_m, ev_hi_m, ev_lo_m, ev_ws_m, evict, keep,
-     ev_k1, st_k1, st_lo_m, pos, hit, fast_ok) = _fastpath_probe_full(
-        state, ev_hi, ev_lo, ev_ws, ev_valid, watermark_cutoff, params)
+    with jax.named_scope("probe"):
+        (late, ev_valid_m, ev_hi_m, ev_lo_m, ev_ws_m, evict, keep,
+         ev_k1, st_k1, st_lo_m, pos, hit, fast_ok) = _fastpath_probe_full(
+            state, ev_hi, ev_lo, ev_ws, ev_valid, watermark_cutoff, params)
 
     def fast(_):
         B = state.hist_bins
@@ -850,9 +854,19 @@ def _merge_fastpath(
                   ev_lon_deg, ev_ts, ev_valid, watermark_cutoff, params)
 
     def not_fast(_):
-        return jax.lax.cond(insert_ok, insert, slow, None)
+        return jax.lax.cond(insert_ok, _scoped("tier_insert", insert),
+                            _scoped("tier_slow", slow), None)
 
-    return jax.lax.cond(fast_ok, fast, not_fast, None)
+    return jax.lax.cond(fast_ok, _scoped("tier_fast", fast), not_fast, None)
+
+
+def _scoped(name: str, branch):
+    """``branch`` of a ``lax.cond`` traced under ``jax.named_scope(name)``,
+    so a device trace names the tier each op ran in."""
+    def run(operand):
+        with jax.named_scope(name):
+            return branch(operand)
+    return run
 
 
 def _apply_routing(

@@ -1,6 +1,6 @@
 """obs — pipeline-wide observability substrate.
 
-Eight pieces, all dependency-free:
+Nine pieces, all dependency-free:
 
 - :mod:`registry` — counters / gauges / fixed-bucket histograms with
   Prometheus text exposition (``Registry.expose_text``);
@@ -24,7 +24,10 @@ Eight pieces, all dependency-free:
 - :mod:`xproc` — the file-backed supervisor→child metrics channel
   (``HEATMAP_SUPERVISOR_CHANNEL``), so the child's ``/metrics`` reports
   its parent supervisor's restart counters and they survive restarts;
-  plus the per-child freshness summary files next to it.
+  plus the per-child freshness summary files next to it;
+- :mod:`spans` — the timed-region helper every layer uses: a
+  ``heatmap_batch_span_seconds`` observation that is also a
+  ``heatmap.<name>`` host span on the profiler's clock.
 
 stream.metrics.Metrics builds on the registry and keeps its historical
 ``snapshot()`` JSON keys — served at ``/metrics.json`` — while

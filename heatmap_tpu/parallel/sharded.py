@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import functools
 import math
-import time
 from typing import NamedTuple, Sequence
 
 import jax
@@ -481,12 +480,6 @@ class ShardedAggregator:
             donate_argnums=donate_state_argnums(),
         )
         self._in_sharding = shard1
-        # host wall spent dispatching the fused sharded step (one fused
-        # program drives every local shard, so one dispatch clock per
-        # HOST — not separable per shard host-side).  Same surface as
-        # MultiAggregator.device_seconds; stream.runtime exports it as
-        # the heatmap_device_dispatch_seconds{shard="0"} gauge.
-        self.device_seconds = [0.0]
         self.n_steps = 0
 
     def instrument(self, wrap) -> None:
@@ -538,7 +531,6 @@ class ShardedAggregator:
         host-precomputed cell keys for THIS host's local rows (same
         local-slice convention as lat_rad); required for EVERY unique
         resolution when given (a partial dict raises)."""
-        t0 = time.monotonic()
         if prekeys is not None:
             missing = [r for r in self._uniq_res if r not in prekeys]
             if missing:
@@ -557,7 +549,6 @@ class ShardedAggregator:
                 jnp.int32(watermark_cutoff),
             )
         self.states = list(states)
-        self.device_seconds[0] += time.monotonic() - t0
         self.n_steps += 1
         return packed
 
@@ -728,12 +719,6 @@ class PartitionedAggregator:
         ]
         self._uniq_res = self.shards[0]._uniq_res
         self.n_steps = 0
-
-    @property
-    def device_seconds(self) -> list:
-        """Per-shard host dispatch clocks (one per device program) —
-        read dynamically by the runtime's callback gauges."""
-        return [sub.device_seconds[0] for sub in self.shards]
 
     @property
     def local_shards(self) -> int:

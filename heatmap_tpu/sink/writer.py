@@ -20,6 +20,7 @@ import threading
 import time
 from typing import Sequence
 
+from heatmap_tpu.obs.spans import span
 from heatmap_tpu.sink.base import Store
 
 log = logging.getLogger(__name__)
@@ -64,6 +65,9 @@ class AsyncWriter:
         # stalls HERE — this counter makes that visible at /metrics
         # (vs. a mystery gap in the batch spans).
         self._backpressure_s = 0.0
+        # the store and view applies are timed on this thread into the
+        # runtime's span family (sink_tiles / sink_positions / sink_view)
+        self._metrics = metrics
         if metrics is not None:
             # queue depth read at scrape time (callback gauge) — a deep
             # queue means the sink can't keep up with the device step;
@@ -132,14 +136,18 @@ class AsyncWriter:
                             log.exception("sink commit-mark callback "
                                           "failed")
                 elif self._exc is None:
-                    n = self._apply(kind, docs)
-                    if kind.startswith("tiles"):
+                    tiles = kind.startswith("tiles")
+                    with span("sink_tiles" if tiles else "sink_positions",
+                              self._metrics):
+                        n = self._apply(kind, docs)
+                    if tiles:
                         self._written_tiles += n
                         if self.audit is not None:
                             self.audit.add("docs_committed", n)
                         if n and self.view is not None \
                                 and not self.view.poisoned:
-                            self._feed_view(kind, docs)
+                            with span("sink_view", self._metrics):
+                                self._feed_view(kind, docs)
                     else:
                         self._written_positions += n
             except BaseException as e:  # poisons the writer permanently

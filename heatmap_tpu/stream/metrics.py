@@ -1,7 +1,8 @@
 """Runtime metrics: the counters BASELINE.json measures (SURVEY.md §5.5).
 
 events/sec in, rows upserted, p50/p95 micro-batch latency, plus per-span
-timings (poll / build / pull / snap / device / sink_submit) so the
+timings (poll / pad / snap / transfer / pull / device / sink_submit /
+prefetch on the step thread; checkpoint and sink applies off it) so the
 bottleneck is visible.  Built on the obs registry: latency, freshness,
 and spans are real fixed-bucket histograms with Prometheus exposition
 (served at /metrics), while ``snapshot()`` keeps every historical JSON
@@ -26,6 +27,14 @@ from heatmap_tpu.obs import (
     Registry,
     render_flat_counters,
 )
+
+# Spans observed one at a time through ``Metrics.observe_span``, outside
+# a batch's span dict and outside span=total: the step thread's
+# checkpoint (flush barrier to the device snapshot's dispatch), the
+# checkpoint commit thread's stages, and the sink writer's applies
+OFF_STEP_SPANS = ("checkpoint", "checkpoint_drain", "checkpoint_to_host",
+                  "checkpoint_write", "sink_tiles", "sink_positions",
+                  "sink_view")
 
 # Counter-dict entries that are point-in-time values, not monotonic
 # counts — typed as gauges in the exposition
@@ -82,8 +91,9 @@ class Metrics:
             buckets=DEFAULT_LAG_BUCKETS)
         self._span_fam = self.registry.histogram(
             "heatmap_batch_span_seconds",
-            "per-batch span wall time (poll/build/pull/snap/device/"
-            "sink_submit; span=total is the whole step)",
+            "per-batch span wall time (poll/pad/snap/transfer/pull/"
+            "device/sink_submit/prefetch; span=total is the whole step; "
+            "checkpoint* and sink_* are timed where they run)",
             labels=("span",), buckets=DEFAULT_TIME_BUCKETS)
         # ---- freshness lineage series (obs.lineage): these measure the
         # END-TO-END quantity the batch spans cannot — event timestamp
@@ -125,8 +135,11 @@ class Metrics:
         # by the runtime when HEATMAP_AUDIT=1; ``drop`` forwards every
         # tagged drop into it so the conservation identity closes
         self.audit = None
-        # name -> histogram child, in observation order (snapshot() keys)
-        self.spans: dict[str, object] = {}
+        # name -> histogram child, in observation order (snapshot() keys);
+        # the off-step spans exist from the start, so the threads that
+        # observe them never insert
+        self.spans: dict[str, object] = {
+            k: self._span_fam.labels(span=k) for k in OFF_STEP_SPANS}
 
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] += n
@@ -170,6 +183,15 @@ class Metrics:
         if t is None:
             t = self.spans["total"] = self._span_fam.labels(span="total")
         t.observe(latency_s)
+
+    def observe_span(self, name: str, seconds: float) -> None:
+        """One span timed outside a batch's span dict, from any thread
+        (the histogram observes under the registry's lock); never
+        touches span=total."""
+        h = self.spans.get(name)
+        if h is None:
+            h = self.spans.setdefault(name, self._span_fam.labels(span=name))
+        h.observe(seconds)
 
     def freshness_summary(self) -> dict:
         """Event-age / ring-residency summary keys — what bench &

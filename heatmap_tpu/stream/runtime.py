@@ -38,13 +38,15 @@ import numpy as np
 from heatmap_tpu.config import Config
 from heatmap_tpu.engine import AggParams
 from heatmap_tpu.engine.state import TileState
+from heatmap_tpu.obs.spans import span
+from heatmap_tpu.obs.spans import step as trace_step
 from heatmap_tpu.sink import AsyncWriter, Store
 from heatmap_tpu.sink.base import PositionRows
 from heatmap_tpu.stream.checkpoint import CheckpointManager
 from heatmap_tpu.stream.events import EventColumns, parse_events
 from heatmap_tpu.stream.metrics import Metrics
 from heatmap_tpu.stream.source import Source
-from heatmap_tpu.stream.trace import Tracer
+from heatmap_tpu.stream.trace import ProfilerTracer
 
 log = logging.getLogger(__name__)
 
@@ -54,6 +56,11 @@ class StateOverflowError(RuntimeError):
     exceed the state slab capacity and aggregates would be dropped."""
 
 I32_MIN = -(2**31)
+
+
+def _state_bytes(states: dict) -> int:
+    """Bytes of the host state snapshots a checkpoint commits."""
+    return sum(int(leaf.nbytes) for st in states.values() for leaf in st)
 
 
 class _FeedBatch(NamedTuple):
@@ -242,7 +249,7 @@ class MicroBatchRuntime:
                 self.hist_compactor.start()
         self.writer = AsyncWriter(store, metrics=self.metrics,
                                   view=self.matview)
-        self.tracer = Tracer()
+        self.tracer = ProfilerTracer()
         from heatmap_tpu.obs import LineageTracker, TraceRing
 
         self.tracering = TraceRing()
@@ -382,10 +389,8 @@ class MicroBatchRuntime:
                 # digest-mismatch dumps correlate under the fleet
                 # episode via this recorder (obs.audit._dump_mismatch)
                 self.audit.flightrec = fr
-        # pipeline-state gauges: watermark/event-time lag, state slab
-        # occupancy vs capacity (the overflow early-warning), and the
-        # per-shard device dispatch clock (engine.multi accumulates it;
-        # callback gauges read it at scrape time)
+        # pipeline-state gauges: watermark/event-time lag and state slab
+        # occupancy vs capacity (the overflow early-warning)
         self._g_watermark = self.metrics.gauge(
             "heatmap_watermark_age_seconds",
             "wall clock minus the event-time high watermark "
@@ -683,18 +688,6 @@ class MicroBatchRuntime:
             for res, win_s in pairs:
                 self.aggs[(res, win_s // 60)] = self._multi.view(res, win_s)
         self._g_capacity.set(cap)
-        # per-shard device dispatch clock: the fused aggregator keeps a
-        # host-wall accumulator per local shard; a callback gauge reads
-        # it at scrape time so the step loop pays nothing extra
-        agg_obs = self._agg()
-        fam = self.metrics.gauge(
-            "heatmap_device_dispatch_seconds",
-            "cumulative host wall seconds spent dispatching the fused "
-            "device step (one clock per local dispatch stream)",
-            labels=("shard",))
-        for shard, _ in enumerate(getattr(agg_obs, "device_seconds", ())):
-            fam.labels(shard=str(shard)).fn = (
-                lambda a=agg_obs, s=shard: a.device_seconds[s])
         # HEATMAP_H3_IMPL=native: snap on the host (C++, ~11x faster per
         # CPU core than the XLA-CPU snap and f64-exact) and feed the fold
         # pre-computed keys — both paths: the fused single-device step
@@ -1327,6 +1320,17 @@ class MicroBatchRuntime:
             # already-dispatched slices on replay.  Wait for the tail to
             # drain (a step or two); the next eligible epoch commits.
             return
+        # the step thread's share of a checkpoint, outside span=total
+        # and outside pull (its flush barrier pulls with heatmap.pull
+        # nested, but the next step resets _last_pull_s)
+        with span("checkpoint", self.metrics):
+            self._checkpoint_capture()
+
+    def _checkpoint_capture(self) -> None:
+        """The flush barrier, then (multi-host) the synchronous commit,
+        or (one host) the join of the previous commit and the dispatch
+        of fresh device copies of every pair's state, handed to the
+        ``ckpt-commit`` thread."""
         # the commit must cover every batch whose offsets it advances past
         self.flush_pending()
         if self._multiproc:
@@ -1343,6 +1347,7 @@ class MicroBatchRuntime:
                 (res, wmin * 60): agg.snapshot()
                 for (res, wmin), agg in self.aggs.items()
             }
+            self.metrics.count("checkpoint_bytes", _state_bytes(states))
             self.ckpt.commit(self._offsets_dispatched, self.max_event_ts,
                              self.epoch, states, shards=self._local_shards,
                              snap_impl=self._snap_impl_name,
@@ -1372,13 +1377,18 @@ class MicroBatchRuntime:
                 # writes queued before the snapshot must be durable before
                 # offsets move; later writes draining too is harmless
                 # (idempotent upserts)
-                self.writer.drain()
-                states = {k: to_host(s) for k, (s, to_host) in snaps.items()}
-                self.ckpt.commit(offset, max_ts, epoch, states,
-                                 shards=self._local_shards,
-                                 snap_impl=self._snap_impl_name,
-                                 mesh_mode=self._mesh_mode,
-                                 extras=extras)
+                with span("checkpoint_drain", self.metrics):
+                    self.writer.drain()
+                with span("checkpoint_to_host", self.metrics):
+                    states = {k: to_host(s)
+                              for k, (s, to_host) in snaps.items()}
+                self.metrics.count("checkpoint_bytes", _state_bytes(states))
+                with span("checkpoint_write", self.metrics):
+                    self.ckpt.commit(offset, max_ts, epoch, states,
+                                     shards=self._local_shards,
+                                     snap_impl=self._snap_impl_name,
+                                     mesh_mode=self._mesh_mode,
+                                     extras=extras)
                 self.metrics.count("checkpoints")
             except BaseException as e:  # surfaced on the step thread
                 self._ckpt_err = e
@@ -1580,13 +1590,19 @@ class MicroBatchRuntime:
         ring drains (checkpoints, close, idle polls, window/growth
         pressure); steady-state flushes instead run per shard
         (_flush_mesh_shard) on each ring's own cadence."""
-        t_flush = time.monotonic()
         if self._mesh_rings is not None:
             for d in range(len(self._mesh_rings)):
                 self._flush_mesh_shard(d)
             return
         if not len(self._ring):
             return
+        with span("pull") as pull:
+            self._flush_ring()
+        self._last_pull_s = pull.seconds
+
+    def _flush_ring(self) -> None:
+        """The single-ring body of ``flush_pending`` (the ``pull``
+        span): one pull of every parked batch, then its accounting."""
         n_batches = len(self._ring)
         batch_max = I32_MIN
         if self._multi is not None:
@@ -1652,7 +1668,6 @@ class MicroBatchRuntime:
         self._last_flush_cutoff = (
             self.max_event_ts - self.cfg.watermark_minutes * 60
             if self.max_event_ts > I32_MIN else I32_MIN)
-        self._last_pull_s = time.monotonic() - t_flush
 
     def _book_flushed_batch(self, bm: int, batch_max: int) -> int:
         """Per-flushed-batch bookkeeping: freshness at the emit boundary
@@ -2131,7 +2146,7 @@ class MicroBatchRuntime:
         """Run one micro-batch; returns False when the source yielded nothing."""
         self._step_began = time.monotonic()
         try:
-            with self.tracer.batch(self.epoch):
+            with self.tracer.batch(self.epoch), trace_step(self.epoch):
                 return self._step_once_inner()
         finally:
             self._step_began = None
@@ -2140,20 +2155,12 @@ class MicroBatchRuntime:
             # it maintains is what the /healthz memory budget reads
             self.runtimeinfo.memory.sample(min_interval_s=1.0)
 
-    def _next_batch(self) -> "_FeedBatch | None":
-        """Produce the next feed batch: carry-drain or source poll,
-        overshoot sliced into the carry, lanes padded to the feed shape,
-        host pre-snap, and an async device_put of the feed lanes so the
-        H2D transfer overlaps the in-flight fold when called from the
-        prefetch stage.  Returns None when the source yielded nothing.
-
-        Sub-span seconds land in the entry (poll with the source's
-        fetch/decode split, build with its pad portion, snap, transfer)
-        and are recorded when the batch is DISPATCHED, so the span
-        percentiles describe the batch they fed regardless of which
-        step paid the work."""
-        spans: dict[str, float] = {}
-        t0 = time.monotonic()
+    def _poll_feed(self, spans: dict):
+        """The poll stage of ``_next_batch``: a carry drain or a source
+        poll (with the source's own sub-spans and the ownership filter),
+        the overshoot sliced into the carry.  Returns (cols, the
+        ownership filter's cells, the lineage poll stamp, the pre-filter
+        timestamps the watermark advances from)."""
         if self._carry_cols is not None:
             # a batch-granular source (columnar values) overshot the feed
             # shape: drain the remainder before polling again.  The
@@ -2189,10 +2196,10 @@ class MicroBatchRuntime:
                 # advance, and the dispatch count must match the
                 # unsharded run's (the slab's per-batch Kahan rewrite
                 # makes state bits a function of it).
-                t_f = time.monotonic()
-                wm_ts = cols.ts_s
-                cols, n_foreign, shard_cells = \
-                    self.shardmap.filter_columns(cols)
+                with span("shard_filter", spans):
+                    wm_ts = cols.ts_s
+                    cols, n_foreign, shard_cells = \
+                        self.shardmap.filter_columns(cols)
                 if n_foreign:
                     # closed drop-reason accounting: oversample-mode
                     # polls EXPECT ~(N-1)/N foreign rows per poll —
@@ -2202,7 +2209,6 @@ class MicroBatchRuntime:
                     self.metrics.drop(
                         "oversample" if self._shard_oversample > 1
                         else "out_of_shard", n_foreign)
-                spans["shard_filter"] = time.monotonic() - t_f
         if cols is not None and len(cols) > self._feed_batch:
             from heatmap_tpu.stream.events import slice_columns
 
@@ -2213,11 +2219,26 @@ class MicroBatchRuntime:
                 shard_cells = shard_cells[:self._feed_batch]
             self._carry_polled_at = t_polled
             cols = slice_columns(cols, 0, self._feed_batch)
+        return cols, shard_cells, t_polled, wm_ts
+
+    def _next_batch(self) -> "_FeedBatch | None":
+        """Produce the next feed batch: carry-drain or source poll,
+        overshoot sliced into the carry, lanes padded to the feed shape,
+        host pre-snap, and an async device_put of the feed lanes so the
+        H2D transfer overlaps the in-flight fold when called from the
+        prefetch stage.  Returns None when the source yielded nothing.
+
+        Sub-span seconds land in the entry (poll with the source's
+        fetch/decode split, pad, snap, transfer) and are recorded when
+        the batch is DISPATCHED, so the span percentiles describe the
+        batch they fed regardless of which step paid the work."""
+        spans: dict[str, float] = {}
         # span_poll keeps its historical meaning — source poll PLUS any
         # host columnarize/parse (_build_batch): the r5 feed-wall was
         # diagnosed from exactly this span, so dict-fed parse time must
         # keep landing here (carry drains bill ~0, as before)
-        spans["poll"] = time.monotonic() - t0
+        with span("poll", spans):
+            cols, shard_cells, t_polled, wm_ts = self._poll_feed(spans)
         if cols is None:
             return None
         # offsets as of THIS poll, applied only when the batch is
@@ -2253,37 +2274,33 @@ class MicroBatchRuntime:
                               offset=offset, carried=carried,
                               spans=spans, lineage=lin, wm_ts=wm_ts,
                               mesh=mesh_blocks)
-        t1 = time.monotonic()
-        valid = np.zeros(self._feed_batch, bool)
-        valid[:n] = True
-        feed = {
-            "lat": self._pad(cols.lat_rad),
-            "lng": self._pad(cols.lng_rad),
-            "speed": self._pad(cols.speed_kmh),
-            "ts": self._pad(cols.ts_s),
-            "valid": valid,
-        }
-        t2 = time.monotonic()
-        spans["pad"] = t2 - t1
+        with span("pad", spans):
+            valid = np.zeros(self._feed_batch, bool)
+            valid[:n] = True
+            feed = {
+                "lat": self._pad(cols.lat_rad),
+                "lng": self._pad(cols.lng_rad),
+                "speed": self._pad(cols.speed_kmh),
+                "ts": self._pad(cols.ts_s),
+                "valid": valid,
+            }
         # host pre-snap (HEATMAP_H3_IMPL=native), shared by both paths
-        agg = self._multi if self._multi is not None else self._sharded
-        prekeys = self._presnap(feed["lat"], feed["lng"], valid, cols,
-                                agg._uniq_res, shard_cells=shard_cells)
-        t3 = time.monotonic()
-        spans["snap"] = t3 - t2
-        if self._multi is not None:
-            # dispatch the H2D transfers NOW (device_put is async): by
-            # the time this batch is folded, its lanes are already
-            # device-resident — from the prefetch stage the transfer
-            # overlaps the previous batch's fold (double buffering).
-            # The sharded path keeps host arrays: its step applies the
-            # mesh shardings itself (ShardedAggregator._puts).
-            feed = {k: jax.device_put(v) for k, v in feed.items()}
-            if prekeys is not None:
-                prekeys = {r: (jax.device_put(hi), jax.device_put(lo))
-                           for r, (hi, lo) in prekeys.items()}
-        spans["transfer"] = time.monotonic() - t3
-        spans["build"] = spans["pad"] + spans["transfer"]
+        with span("snap", spans):
+            agg = self._multi if self._multi is not None else self._sharded
+            prekeys = self._presnap(feed["lat"], feed["lng"], valid, cols,
+                                    agg._uniq_res, shard_cells=shard_cells)
+        with span("transfer", spans):
+            if self._multi is not None:
+                # dispatch the H2D transfers NOW (device_put is async): by
+                # the time this batch is folded, its lanes are already
+                # device-resident — from the prefetch stage the transfer
+                # overlaps the previous batch's fold (double buffering).
+                # The sharded path keeps host arrays: its step applies the
+                # mesh shardings itself (ShardedAggregator._puts).
+                feed = {k: jax.device_put(v) for k, v in feed.items()}
+                if prekeys is not None:
+                    prekeys = {r: (jax.device_put(hi), jax.device_put(lo))
+                               for r, (hi, lo) in prekeys.items()}
         return _FeedBatch(cols=cols, n=n, feed=feed, prekeys=prekeys,
                           offset=offset, carried=carried, spans=spans,
                           lineage=lin, wm_ts=wm_ts)
@@ -2358,7 +2375,7 @@ class MicroBatchRuntime:
             wm_max - self.cfg.watermark_minutes * 60
             if wm_max > I32_MIN else I32_MIN
         )
-        infer_s = 0.0
+        step_spans: dict[str, float] = {}
         if self.infer is not None and cols is not None:
             # reducer fold BEFORE the device dispatch, not after: the
             # Kalman scan shares the XLA CPU queue with the window-fold
@@ -2368,28 +2385,127 @@ class MicroBatchRuntime:
             # has already drained the device, so the scan runs against
             # an idle queue and the window fold then overlaps the NEXT
             # batch's feed exactly as before
-            t_inf = time.monotonic()
-            self.infer.fold_batch(cols)
-            ievents = self.infer.drain_anomalies()
-            if ievents and self.matview is not None:
-                # anomaly records ride the writer thread like every view
-                # mutation (single-writer discipline), then fan out via
-                # the view's feed hook + watchers: repl followers and
-                # the anomaly continuous-query engine see them at zero
-                # extra writer cost.  They carry no doc mutations, so
-                # queueing ahead of this batch's (deferred) doc applies
-                # is order-safe.
-                grid = self.cfg.default_grid()
-                view = self.matview
-                self.writer.submit_mark(
-                    lambda: view.publish_anomalies(grid, ievents))
-            infer_s = time.monotonic() - t_inf
+            with span("infer", step_spans):
+                self.infer.fold_batch(cols)
+                ievents = self.infer.drain_anomalies()
+                if ievents and self.matview is not None:
+                    # anomaly records ride the writer thread like every view
+                    # mutation (single-writer discipline), then fan out via
+                    # the view's feed hook + watchers: repl followers and
+                    # the anomaly continuous-query engine see them at zero
+                    # extra writer cost.  They carry no doc mutations, so
+                    # queueing ahead of this batch's (deferred) doc applies
+                    # is order-safe.
+                    grid = self.cfg.default_grid()
+                    view = self.matview
+                    self.writer.submit_mark(
+                        lambda: view.publish_anomalies(grid, ievents))
         if self.infer is not None:
             # frozen as of this batch's fold: the velocity columns of its
             # tiles do not depend on when its emit ring is pulled (the
             # per-device cadence of a mesh, emit_flush_k, the governor)
             self._vel_views[self.epoch] = self.infer.velocity_view()
-        t_ready = time.monotonic()
+        with span("device", step_spans):
+            self._dispatch(entry, cutoff)
+        with span("sink_submit", step_spans):
+            if self.positions_enabled and cols is not None:
+                prows = self._fold_positions(cols)
+                if prows is not None:
+                    self.writer.submit_positions_packed(prows)
+                    self.metrics.count("positions_emitted",
+                                       len(prows.ts_ms))
+            self.epoch += 1
+        # refill the prefetch queue AFTER the dispatch: the next batch's
+        # poll/decode/pad and its device_put run while the device folds
+        # the batch just dispatched (the double-buffered feed)
+        with span("prefetch", step_spans):
+            if (self._prefetch_n and not self._multiproc
+                    and not self._closing):
+                while len(self._prefetched) < self._prefetch_n:
+                    nxt = self._next_batch()
+                    if nxt is None:
+                        break
+                    self._prefetched.append(nxt)
+        t_end = time.monotonic()
+        pull_s, self._last_pull_s = self._last_pull_s, 0.0
+        espans = entry.spans
+        spans = {
+            # feed-stage spans describe THIS batch even when the work
+            # was paid by an earlier step's prefetch stage
+            "poll": espans.get("poll", 0.0),
+            # sub-splits of the feed (satellite telemetry): pad vs H2D
+            # transfer; the poll's own fetch/decode split follows below
+            "pad": espans.get("pad", 0.0),
+            "transfer": espans.get("transfer", 0.0),
+            # the deferred pull of up to K parked batches (waits out
+            # their folds) vs this batch's own dispatch — the split that
+            # shows whether checkpoint/pull work ever gaps the step loop
+            "pull": pull_s,
+            # host pre-snap (HEATMAP_H3_IMPL=native) is host work
+            # billed separately from the device dispatch it precedes
+            "snap": espans.get("snap", 0.0),
+            "device": step_spans["device"],
+            "sink_submit": step_spans["sink_submit"],
+            # this step's prefetch refill (the NEXT batch's feed stage,
+            # overlapping the fold just dispatched)
+            "prefetch": step_spans["prefetch"],
+        }
+        for k in ("poll_fetch", "poll_decode", "poll_wait", "partition",
+                  "shard_filter"):
+            if k in espans:
+                spans[k] = espans[k]
+        if self.infer is not None:
+            # reducer-set fold cost as ITS OWN span (it runs pre-
+            # dispatch, between feed and device, so no other span
+            # absorbs it) — a composed-fold regression shows up here,
+            # not as a mystery elsewhere
+            spans["infer"] = step_spans.get("infer", 0.0)
+        self.metrics.observe_batch(t_end - t0, spans)
+        # structured trace record (obs.tracebuf -> /trace/recent, JSONL).
+        # Late/overflow counts account up to emit_flush_k batches behind
+        # (the deferred pull), so the record carries the delta since the
+        # last record — a nonzero flag points at the incident window
+        # either way.
+        c = self.metrics.counters
+        cum = (c.get("events_late", 0), c.get("state_overflow_groups", 0),
+               c.get("events_bucket_dropped", 0))
+        last = getattr(self, "_trace_cum", (0, 0, 0))
+        self._trace_cum = cum
+        self.tracering.record(
+            self.epoch - 1, t_end - t0, spans, n_events=n,
+            n_late=cum[0] - last[0], overflow_groups=cum[1] - last[1],
+            late_dropped=cum[2] - last[2])
+        progressed = cols is not None
+        carrying = self._carried_last
+        if self._multiproc:
+            # fixed-position collective: every host contributes
+            # (had-events, still-live, mid-carry); the summed triple is
+            # identical everywhere, so all hosts take the same run()-loop
+            # branch AND the same checkpoint-skip decision (a one-sided
+            # skip would deadlock the checkpoint barrier)
+            had, live, carry_any = self._gpair(
+                float(progressed),
+                0.0 if self.source.exhausted else 1.0,
+                float(carrying))
+            self._global_live = live
+            progressed = had > 0
+            carrying = carry_any > 0
+        if self.checkpoint_every and self.epoch % self.checkpoint_every == 0:
+            # cadence hit; if mid-carry, the flag holds the commit until
+            # the FIRST carry-free step (a fixed record:feed size ratio can
+            # make "cadence epoch AND carry-free" never align, so waiting
+            # for the next cadence hit could starve checkpoints forever)
+            self._ckpt_due = True
+        if self._ckpt_due and not carrying:
+            self._ckpt_due = False
+            self._checkpoint()
+        return progressed
+
+    def _dispatch(self, entry: "_FeedBatch", cutoff: int) -> None:
+        """Hand one feed batch to the device fold (async) and book it:
+        lineage, the parked emit ring entry, governor and audit counts,
+        offsets, and the host-side watermark advance."""
+        cols, n, feed = entry.cols, entry.n, entry.feed
         prekeys = entry.prekeys
         if cols is None and self._host_snap is not None:
             # idle lockstep batch under the native snap: cached zero keys
@@ -2490,99 +2606,6 @@ class MicroBatchRuntime:
                 self.max_event_ts = bm
                 self._g_watermark.set(time.time() - bm)
         self._publish_shard_watermark()
-        t_device = time.monotonic()
-
-        if self.positions_enabled and cols is not None:
-            prows = self._fold_positions(cols)
-            if prows is not None:
-                self.writer.submit_positions_packed(prows)
-                self.metrics.count("positions_emitted", len(prows.ts_ms))
-        self.epoch += 1
-        t_sink = time.monotonic()
-        # refill the prefetch queue AFTER the dispatch: the next batch's
-        # poll/decode/pad and its device_put run while the device folds
-        # the batch just dispatched (the double-buffered feed)
-        if self._prefetch_n and not self._multiproc and not self._closing:
-            while len(self._prefetched) < self._prefetch_n:
-                nxt = self._next_batch()
-                if nxt is None:
-                    break
-                self._prefetched.append(nxt)
-        t_end = time.monotonic()
-        pull_s, self._last_pull_s = self._last_pull_s, 0.0
-        espans = entry.spans
-        spans = {
-            # feed-stage spans describe THIS batch even when the work
-            # was paid by an earlier step's prefetch stage
-            "poll": espans.get("poll", 0.0),
-            "build": espans.get("build", 0.0),
-            # sub-splits of poll/build (satellite telemetry): source
-            # fetch vs decode, pad vs H2D transfer
-            "pad": espans.get("pad", 0.0),
-            "transfer": espans.get("transfer", 0.0),
-            # the deferred pull of up to K parked batches (waits out
-            # their folds) vs this batch's own dispatch — the split that
-            # shows whether checkpoint/pull work ever gaps the step loop
-            "pull": pull_s,
-            # host pre-snap (HEATMAP_H3_IMPL=native) is host work
-            # billed separately from the device dispatch it precedes
-            "snap": espans.get("snap", 0.0),
-            "device": (t_device - t_ready),
-            "sink_submit": t_sink - t_device,
-            # this step's prefetch refill (the NEXT batch's feed stage,
-            # overlapping the fold just dispatched)
-            "prefetch": t_end - t_sink,
-        }
-        for k in ("poll_fetch", "poll_decode", "poll_wait", "partition",
-                  "shard_filter"):
-            if k in espans:
-                spans[k] = espans[k]
-        if self.infer is not None:
-            # reducer-set fold cost as ITS OWN span (it runs pre-
-            # dispatch, between feed and device, so no other span
-            # absorbs it) — a composed-fold regression shows up here,
-            # not as a mystery elsewhere
-            spans["infer"] = infer_s
-        self.metrics.observe_batch(t_end - t0, spans)
-        # structured trace record (obs.tracebuf -> /trace/recent, JSONL).
-        # Late/overflow counts account up to emit_flush_k batches behind
-        # (the deferred pull), so the record carries the delta since the
-        # last record — a nonzero flag points at the incident window
-        # either way.
-        c = self.metrics.counters
-        cum = (c.get("events_late", 0), c.get("state_overflow_groups", 0),
-               c.get("events_bucket_dropped", 0))
-        last = getattr(self, "_trace_cum", (0, 0, 0))
-        self._trace_cum = cum
-        self.tracering.record(
-            self.epoch - 1, t_end - t0, spans, n_events=n,
-            n_late=cum[0] - last[0], overflow_groups=cum[1] - last[1],
-            late_dropped=cum[2] - last[2])
-        progressed = cols is not None
-        carrying = self._carried_last
-        if self._multiproc:
-            # fixed-position collective: every host contributes
-            # (had-events, still-live, mid-carry); the summed triple is
-            # identical everywhere, so all hosts take the same run()-loop
-            # branch AND the same checkpoint-skip decision (a one-sided
-            # skip would deadlock the checkpoint barrier)
-            had, live, carry_any = self._gpair(
-                float(progressed),
-                0.0 if self.source.exhausted else 1.0,
-                float(carrying))
-            self._global_live = live
-            progressed = had > 0
-            carrying = carry_any > 0
-        if self.checkpoint_every and self.epoch % self.checkpoint_every == 0:
-            # cadence hit; if mid-carry, the flag holds the commit until
-            # the FIRST carry-free step (a fixed record:feed size ratio can
-            # make "cadence epoch AND carry-free" never align, so waiting
-            # for the next cadence hit could starve checkpoints forever)
-            self._ckpt_due = True
-        if self._ckpt_due and not carrying:
-            self._ckpt_due = False
-            self._checkpoint()
-        return progressed
 
     def _presnap(self, lat, lng, valid, cols, uniq_res, shard_cells=None):
         """Host C++ cell keys for this batch (HEATMAP_H3_IMPL=native), or
@@ -2643,34 +2666,31 @@ class MicroBatchRuntime:
         fold).  Under a per-shard governor a device whose rows exceed
         its bucket dispatches multiple chunks — regrouping, never
         dropping (the PR 10 exact-regrouping discipline)."""
-        t0 = time.monotonic()
-        reuse = None
-        if (shard_cells is not None and self.meshmap.native
-                and len(shard_cells) == len(cols)):
-            # composed process+mesh sharding: the ownership filter
-            # already snapped these rows at the same (coarsest-res)
-            # partition key — no second host snap
-            reuse = shard_cells
-        ids, cells = self.meshmap.partition(cols.lat_rad, cols.lng_rad,
-                                            cells=reuse)
-        spans["partition"] = time.monotonic() - t0
-        t1 = time.monotonic()
+        with span("partition", spans):
+            reuse = None
+            if (shard_cells is not None and self.meshmap.native
+                    and len(shard_cells) == len(cols)):
+                # composed process+mesh sharding: the ownership filter
+                # already snapped these rows at the same (coarsest-res)
+                # partition key — no second host snap
+                reuse = shard_cells
+            ids, cells = self.meshmap.partition(cols.lat_rad, cols.lng_rad,
+                                                cells=reuse)
         govs = self._mesh_governors
         blocks = []
-        for d in range(self._parted.n_shards):
-            idx = np.flatnonzero(ids == d)
-            bucket = (govs[d].batch_rows if govs is not None
-                      else self._feed_batch)
-            if idx.size == 0:
-                blocks.append([None])
-                continue
-            chunks = []
-            for lo in range(0, int(idx.size), bucket):
-                chunks.append(self._mesh_chunk(
-                    cols, idx[lo:lo + bucket], cells, bucket, d))
-            blocks.append(chunks)
-        spans["pad"] = time.monotonic() - t1
-        spans["build"] = spans["pad"]
+        with span("pad", spans):
+            for d in range(self._parted.n_shards):
+                idx = np.flatnonzero(ids == d)
+                bucket = (govs[d].batch_rows if govs is not None
+                          else self._feed_batch)
+                if idx.size == 0:
+                    blocks.append([None])
+                    continue
+                chunks = []
+                for lo in range(0, int(idx.size), bucket):
+                    chunks.append(self._mesh_chunk(
+                        cols, idx[lo:lo + bucket], cells, bucket, d))
+                blocks.append(chunks)
         return blocks
 
     def _mesh_chunk(self, cols, sel, cells, bucket: int, d: int) -> dict:
@@ -2779,7 +2799,12 @@ class MicroBatchRuntime:
         ring = self._mesh_rings[d]
         if not len(ring):
             return
-        t0 = time.monotonic()
+        with span("pull") as pull:
+            self._flush_mesh_ring(d, ring)
+        self._last_pull_s += pull.seconds
+
+    def _flush_mesh_ring(self, d: int, ring) -> None:
+        """The body of ``_flush_mesh_shard`` (the ``pull`` span)."""
         from heatmap_tpu.engine.multi import stats_from_packed
 
         n_batches = len(ring)
@@ -2815,7 +2840,6 @@ class MicroBatchRuntime:
         self._last_flush_cutoff = (
             self.max_event_ts - self.cfg.watermark_minutes * 60
             if self.max_event_ts > I32_MIN else I32_MIN)
-        self._last_pull_s += time.monotonic() - t0
 
     def _note_mesh_flushed(self, epoch: int, residency) -> None:
         """Per-(shard, batch) flush accounting on the partitioned mesh:

@@ -3,8 +3,14 @@ metric is p50 micro-batch latency, so the hot loop must be traceable).
 
 Two layers:
 
-- wall-clock spans per batch (poll / build / device / sink_submit) feed
-  ``stream.metrics`` and surface at /metrics — always on, nanosecond-cheap.
+- spans: ``obs.spans.span`` times a region of the runtime with
+  ``time.monotonic()`` into ``stream.metrics`` (the
+  ``heatmap_batch_span_seconds{span=...}`` family, served at /metrics)
+  and opens ``jax.profiler.TraceAnnotation("heatmap.<name>")`` around
+  it, so the same regions name the host's time on the profiler's clock
+  in a device trace.  ``obs.spans.step`` marks each micro-batch as a
+  profiler step (``heatmap.step``).  Always on: with no profiler
+  session an annotation is a few hundred nanoseconds.
 - a ``jax.profiler`` device trace over a WINDOW of micro-batches,
   armed two ways:
 
@@ -16,10 +22,9 @@ Two layers:
     tracer for a fresh window without a restart, the operability gap
     the boot-only env left open.
 
-  The capture is viewable in TensorBoard / Perfetto; each batch is
-  wrapped in a ``StepTraceAnnotation`` so device ops group by
-  micro-batch.  One window may be in flight at a time: ``arm`` refuses
-  (returns False → HTTP 409) while a window is pending or active.
+  The capture is viewable in TensorBoard / Perfetto.  One window may
+  be in flight at a time: ``arm`` refuses (returns False → HTTP 409)
+  while a window is pending or active.
 """
 
 from __future__ import annotations
@@ -121,9 +126,7 @@ class ProfilerTracer:
             active = self._active
         if active:
             try:
-                with jax.profiler.StepTraceAnnotation("microbatch",
-                                                      step_num=epoch):
-                    yield
+                yield
             finally:
                 # stop at window end, and on an exception escaping the
                 # batch — a dangling trace would be lost and would block
@@ -156,8 +159,3 @@ class ProfilerTracer:
             log.info("profiler: trace written to %s", self.dir)
         except Exception as e:
             log.warning("profiler stop failed: %s", e)
-
-
-# Historical name (PR 1 docstrings and the runtime import the short
-# form; the ISSUE/serve layer use the explicit one).
-Tracer = ProfilerTracer

@@ -16,6 +16,7 @@ Two rules, one place:
 from __future__ import annotations
 
 import os
+import re
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -23,9 +24,20 @@ CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def enable_compile_cache() -> str:
-    """Turn on JAX's persistent compile cache; returns its directory."""
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    The cache is keyed on the programs' metadata too (op names, named
+    scopes, source locations), so an executable read back from it
+    carries the names of the code that asked for it: keyed without them,
+    two versions that differ only in their scopes would share entries,
+    and a device trace would name ops after whichever compiled first.
+    Source locations are relative to the checkout, so the same code
+    checked out in another directory finds the same entries."""
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(CHECKOUT + os.sep))
     chosen = os.environ.get(CACHE_ENV)
     if chosen:
         return chosen

@@ -62,6 +62,61 @@ def test_cache_helper_in_process_matches_jax_config(monkeypatch):
         REPO, ".jax_cache")
 
 
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "checkout"])
+def test_cache_is_keyed_on_program_metadata(tmp_path, env_dir):
+    """Two versions of a program that differ only in their named scopes
+    get cache entries of their own, so a profile of either names its
+    ops after its own scopes."""
+    code = (
+        "import jax\n"
+        "from heatmap_tpu.utils.jaxenv import enable_compile_cache\n"
+        "enable_compile_cache()\n"
+        "print(jax.config.jax_compilation_cache_include_metadata_in_key)\n")
+    if env_dir:
+        r = _python(code, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    else:
+        r = _python(code, drop=("JAX_COMPILATION_CACHE_DIR",))
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == ["True"]
+
+
+def test_cache_entries_do_not_depend_on_the_checkouts_directory(tmp_path):
+    """The same code checked out in two directories compiles once: the
+    source locations in the key are relative to the checkout."""
+    import shutil
+
+    cache = tmp_path / "cache"
+    code = (
+        "import os, jax, jax.numpy as jnp\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)\n"
+        "from heatmap_tpu.utils.jaxenv import enable_compile_cache\n"
+        "enable_compile_cache()\n"
+        "from heatmap_tpu.engine.step import p95_from_hist_device\n"
+        "f = jax.jit(p95_from_hist_device, static_argnums=2)\n"
+        "f(jnp.ones((4, 8), jnp.int32), jnp.full(4, 8, jnp.int32), 64.0)\n"
+        "import heatmap_tpu\n"
+        "print(heatmap_tpu.__file__)\n"
+        "print(sorted(n for n in os.listdir(os.environ"
+        "['JAX_COMPILATION_CACHE_DIR']) if n.endswith('-cache')))\n")
+    seen = []
+    for side in ("a", "b"):
+        root = tmp_path / side
+        shutil.copytree(os.path.join(REPO, "heatmap_tpu"),
+                        root / "heatmap_tpu",
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        env = dict(os.environ, PYTHONPATH=str(root), JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(cache))
+        r = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr
+        imported, entries = r.stdout.strip().split("\n")
+        assert imported.startswith(str(root))   # the copy, not the repo
+        seen.append(entries)
+    assert seen[0] != "[]"
+    assert seen[1] == seen[0]
+
+
 def test_chosen_cpu_is_accepted():
     from heatmap_tpu.utils.jaxenv import require_accelerator
 

@@ -98,3 +98,25 @@ def test_pair_view_checkpoint_roundtrip(rng):
                             emit_capacity=N, hist_bins=0)
     with pytest.raises(ValueError):
         small.view(*PAIRS[0]).restore(snap[PAIRS[0]])
+
+
+def test_step_names_its_stages():
+    """The lowered fused step of a res 7/8/9 pyramid carries the named
+    scopes a device trace attributes its ops by: one snap per
+    resolution, one merge per pair, the pack, and inside each merge the
+    probe and the three tiers of the fast path."""
+    import jax.numpy as jnp
+
+    pairs = [(7, 300), (8, 300), (9, 300)]
+    multi = MultiAggregator(pairs, capacity=CAP, batch_size=N,
+                            emit_capacity=N, hist_bins=BINS)
+    zf = jnp.zeros(N, jnp.float32)
+    text = multi._step.lower(
+        tuple(multi.states), zf, zf, zf, jnp.zeros(N, jnp.int32),
+        jnp.zeros(N, bool), jnp.int32(0)).as_text(debug_info=True)
+    for res in (7, 8, 9):
+        assert f"snap_r{res}/" in text, res
+        assert f"merge_r{res}_w5/" in text, res
+    for scope in ("pack/", "probe/", "tier_fast/", "tier_insert/",
+                  "tier_slow/"):
+        assert scope in text, scope

@@ -536,7 +536,7 @@ def test_trace_recent_records(tmp_path):
         assert newest["epoch"] > tr["traces"][1]["epoch"]
         assert set(newest) >= {"epoch", "t_wall", "latency_ms", "spans_ms",
                                "n_events", "n_late", "overflow_groups"}
-        assert set(newest["spans_ms"]) >= {"poll", "build", "device",
+        assert set(newest["spans_ms"]) >= {"poll", "pad", "device",
                                            "sink_submit"}
     finally:
         httpd.shutdown()

@@ -1,11 +1,17 @@
-"""ProfilerTracer (stream/trace.py): window accounting, env handling,
-partial-capture flush, double-stop safety, runtime re-arming."""
+"""obs/spans.py's span helper (histogram sums, ``heatmap.<name>``
+profiler annotations, thread-safe off-step observations) and
+stream/trace.py's ProfilerTracer (window accounting, env handling,
+partial-capture flush, double-stop safety, runtime re-arming)."""
 
+import sys
 import threading
 
 import pytest
 
-from heatmap_tpu.stream.trace import ProfilerTracer, Tracer
+from heatmap_tpu.obs import spans as spans_mod
+from heatmap_tpu.obs.spans import span
+from heatmap_tpu.stream.metrics import OFF_STEP_SPANS, Metrics
+from heatmap_tpu.stream.trace import ProfilerTracer
 
 
 class FakeProfiler:
@@ -16,6 +22,35 @@ class FakeProfiler:
         self.stops = 0
         self._start_raises = start_raises
 
+        # (event, annotation name) in order: "enter" / "exit" of each
+        # TraceAnnotation, "step" for each StepTraceAnnotation
+        self.events = []
+        fp = self
+
+        class TraceAnnotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                fp.events.append(("enter", self.name))
+                return self
+
+            def __exit__(self, *exc):
+                fp.events.append(("exit", self.name))
+                return False
+
+        class StepTraceAnnotation(TraceAnnotation):
+            def __init__(self, name, step_num=0):
+                super().__init__(name)
+                self.step_num = step_num
+
+            def __enter__(self):
+                fp.events.append(("step", self.name, self.step_num))
+                return self
+
+        self.TraceAnnotation = TraceAnnotation
+        self.StepTraceAnnotation = StepTraceAnnotation
+
     def start_trace(self, d):
         if self._start_raises:
             raise self._start_raises
@@ -23,16 +58,6 @@ class FakeProfiler:
 
     def stop_trace(self):
         self.stops += 1
-
-    class StepTraceAnnotation:
-        def __init__(self, name, step_num=0):
-            pass
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
 
 
 @pytest.fixture()
@@ -50,8 +75,143 @@ def _run_batches(tr, n, start=0):
             pass
 
 
-def test_alias_is_the_same_class():
-    assert Tracer is ProfilerTracer
+class FakeClock:
+    """Stands in for time.monotonic: each read advances by ``step``."""
+
+    def __init__(self, step=0.25):
+        self.t, self.step = 100.0, step
+
+    def __call__(self):
+        self.t += self.step
+        return self.t
+
+
+@pytest.fixture()
+def clock(monkeypatch):
+    c = FakeClock()
+    monkeypatch.setattr(spans_mod.time, "monotonic", c)
+    return c
+
+
+def test_span_times_into_batch_dict_inside_annotation(fake, clock):
+    spans = {}
+    with span("pad", spans) as sp:
+        fake.events.append(("body",))
+    # one clock read on entry, one on exit: exactly the pair it replaces
+    assert spans == {"pad": 0.25} and sp.seconds == 0.25
+    assert fake.events == [("enter", "heatmap.pad"), ("body",),
+                           ("exit", "heatmap.pad")]
+
+
+def test_span_adds_to_a_name_already_timed_and_nests(fake, clock):
+    spans = {"poll_fetch": 1.0}
+    with span("poll", spans):
+        with span("shard_filter", spans):
+            pass
+    with span("poll", spans):
+        pass
+    assert spans == {"poll_fetch": 1.0, "shard_filter": 0.25,
+                     "poll": 0.75 + 0.25}
+    names = [e[1] for e in fake.events]
+    assert names == ["heatmap.poll", "heatmap.shard_filter",
+                     "heatmap.shard_filter", "heatmap.poll",
+                     "heatmap.poll", "heatmap.poll"]
+
+
+def test_span_closes_annotation_and_records_on_exception(fake, clock):
+    spans = {}
+    with pytest.raises(RuntimeError):
+        with span("device", spans):
+            raise RuntimeError("boom")
+    assert spans == {"device": 0.25}
+    assert fake.events[-1] == ("exit", "heatmap.device")
+
+
+def test_span_without_sink_only_annotates(fake, clock):
+    with span("pull") as sp:
+        pass
+    assert sp.seconds == 0.25
+    assert fake.events == [("enter", "heatmap.pull"),
+                           ("exit", "heatmap.pull")]
+
+
+def test_batch_spans_write_the_same_histogram_sums(fake, clock):
+    """Spans timed by the helper reach heatmap_batch_span_seconds through
+    observe_batch exactly as the dict values say; total is the step's
+    own latency, not a sum of spans."""
+    m = Metrics()
+    for _ in range(3):
+        spans = {}
+        for name in ("poll", "pad", "transfer", "device"):
+            with span(name, spans):
+                pass
+        m.observe_batch(2.0, spans)
+    for name in ("poll", "pad", "transfer", "device"):
+        h = m.spans[name]
+        assert h.count == 3 and h.sum == pytest.approx(0.75)
+    assert m.spans["total"].count == 3
+    assert m.spans["total"].sum == pytest.approx(6.0)
+
+
+def test_observe_span_outside_total(fake, clock):
+    m = Metrics()
+    assert set(OFF_STEP_SPANS) <= set(m.spans)   # children exist up front
+    with span("checkpoint", m):
+        pass
+    assert m.spans["checkpoint"].count == 1
+    assert m.spans["checkpoint"].sum == pytest.approx(0.25)
+    assert "total" not in m.spans and m.batch_latency.count == 0
+    assert fake.events == [("enter", "heatmap.checkpoint"),
+                           ("exit", "heatmap.checkpoint")]
+
+
+def test_observe_span_from_eight_threads_loses_nothing():
+    """Eight threads observe off-step spans, two of them the same one,
+    while the step thread records batches: every observation lands."""
+    m = Metrics()
+    n, per = 8, 2000
+    names = [OFF_STEP_SPANS[i % len(OFF_STEP_SPANS)] for i in range(n)]
+    barrier = threading.Barrier(n + 1)
+
+    def work(name):
+        barrier.wait()
+        for _ in range(per):
+            m.observe_span(name, 0.001)
+
+    ts = [threading.Thread(target=work, args=(nm,)) for nm in names]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)   # switch threads as often as possible
+    try:
+        [t.start() for t in ts]
+        barrier.wait(timeout=60)
+        for _ in range(per):
+            m.observe_batch(0.01, {"device": 0.002})
+        for t in ts:
+            t.join(timeout=60)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(switch)
+    for name in set(names):
+        k = names.count(name)
+        assert m.spans[name].count == k * per
+        assert m.spans[name].sum == pytest.approx(0.001 * k * per)
+    assert m.spans["total"].count == m.spans["device"].count == per
+
+
+def test_step_marker_is_heatmap_step(fake):
+    with spans_mod.step(7):
+        pass
+    assert fake.events[0] == ("step", "heatmap.step", 7)
+
+
+def test_tracer_window_adds_no_step_annotation(fake, tmp_path):
+    """The always-on heatmap.step marker replaced the window's own."""
+    tr = ProfilerTracer(env={"HEATMAP_PROFILE_DIR": str(tmp_path),
+                             "HEATMAP_PROFILE_SKIP": "0",
+                             "HEATMAP_PROFILE_BATCHES": "2"})
+    _run_batches(tr, 2)
+    assert fake.starts == [str(tmp_path)] and fake.stops == 1
+    assert fake.events == []
 
 
 def test_disabled_without_dir_never_touches_profiler(fake):
