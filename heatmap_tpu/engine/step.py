@@ -10,7 +10,8 @@ with a single jitted XLA program:
      heatmap_stream.py:96-108, and its 10-minute watermark drop, :107).
   2. ``merge_batch`` — merge-sort the batch into the compact sorted state
      slab: one ``lax.sort`` over (state ∥ batch) keys, segment-id
-     derivation, then masked scatters to rebuild the slab.  Watermark
+     derivation, then the slab rebuilt: kept rows gathered through the
+     inverse routing map, the batch's rows scattered on top.  Watermark
      eviction of closed windows is folded into the same sort (evicted rows
      are relabeled EMPTY so they sink to the tail and their slots recycle).
 
@@ -386,7 +387,7 @@ def _merge_sort(
     has_empty = ~nonempty[-1]  # empties (if any) sort last
     n_distinct = n_seg_total - has_empty.astype(jnp.int32)
     return _apply_routing(state, ev_hi, ev_lo, ev_ws, ev_speed, ev_lat_deg,
-                          ev_lon_deg, ev_ts, ev_valid, late, evict, keep,
+                          ev_lon_deg, ev_ts, ev_valid, late, evict,
                           state_seg, batch_seg, n_distinct, params)
 
 
@@ -445,7 +446,7 @@ def _merge_rank(
     state_seg, batch_seg, n_distinct = _route_via_uniques(
         c1, c2, pos_k, keep, n_keep, u1, u2, uid_of_event, ev_valid, C)
     return _apply_routing(state, ev_hi, ev_lo, ev_ws, ev_speed, ev_lat_deg,
-                          ev_lon_deg, ev_ts, ev_valid, late, evict, keep,
+                          ev_lon_deg, ev_ts, ev_valid, late, evict,
                           state_seg, batch_seg, n_distinct, params)
 
 
@@ -631,7 +632,7 @@ def _merge_probe(
     state_seg, batch_seg, n_distinct = jax.lax.cond(
         fallback, sort_route, probe_route, None)
     return _apply_routing(state, ev_hi, ev_lo, ev_ws, ev_speed, ev_lat_deg,
-                          ev_lon_deg, ev_ts, ev_valid, late, evict, keep,
+                          ev_lon_deg, ev_ts, ev_valid, late, evict,
                           state_seg, batch_seg, n_distinct, params)
 
 
@@ -684,7 +685,7 @@ def _merge_fastpath(
 
     In a warmed stream most batches touch ONLY existing (cell, window)
     groups and evict nothing — yet every impl above rebuilds the entire
-    slab (sort/scatter every lane of every row) per batch, which is the
+    slab (routes and moves every lane of every row) per batch, which is the
     dominant cost at production shapes (~4/5 of the fold wall on CPU,
     round-5 attribution).  This wrapper binary-searches each event
     against the sorted slab directly (no batch sort, no dedup —
@@ -714,7 +715,11 @@ def _merge_fastpath(
     its no-new-keys/no-evict conditions — including the slow path's
     Kahan rewrite of untouched rows (sum' = sum - comp, comp' absorbs
     it) — and tier 2 feeds `_apply_routing` itself with rank-identical
-    routing tables.  Differential-tested per batch
+    routing tables.  With no new keys and no evictions the inverse
+    routing map `_apply_routing` gathers kept rows through is the
+    identity, so tier 1 reads the slab in place; the map is exact in
+    every tier because the slab is sorted with unique keys, which makes
+    `state_seg` injective.  Differential-tested per batch
     (tests/test_merge_fastpath.py).  The slab's sorted invariant is
     preserved by every tier.
 
@@ -844,7 +849,7 @@ def _merge_fastpath(
             jnp.where(miss, batch_seg_u, C))
         return _apply_routing(state, ev_hi_m, ev_lo_m, ev_ws_m, ev_speed,
                               ev_lat_deg, ev_lon_deg, ev_ts, ev_valid_m,
-                              late, evict, keep, state_seg, batch_seg,
+                              late, evict, state_seg, batch_seg,
                               n_distinct, params)
 
     def slow(_):
@@ -872,129 +877,147 @@ def _scoped(name: str, branch):
 def _apply_routing(
     state: TileState,
     ev_hi, ev_lo, ev_ws, ev_speed, ev_lat_deg, ev_lon_deg, ev_ts, ev_valid,
-    late, evict, keep,
+    late, evict,
     state_seg, batch_seg, n_distinct,
     params: AggParams,
 ):
     """Shared epilogue: rebuild the slab from the routing tables, build the
-    update-mode emit, and assemble StepStats."""
+    update-mode emit, and assemble StepStats.
+
+    Kept state rows move by GATHER through the inverse routing map, and
+    only the batch's rows scatter on top.  This is exact: the slab is
+    sorted and its keys are unique, so ``state_seg`` is injective on the
+    kept rows (evicted and overflowed rows route to >= C) and each new
+    row receives at most one state row — ``src[j]``.  A scatter-add of
+    the kept rows into zeros is then a permutation, and the gather
+    reproduces it bit for bit (its ``0.0 + v`` turned a -0.0 into +0.0,
+    and so does the gather).  The rebuild runs under the named scope
+    ``rewrite``."""
     C = state.capacity
     B = state.hist_bins
-
-    # --- rebuild the slab ------------------------------------------------
-    # keys scatter from the ORIGINAL arrays via the routing maps (the sort
-    # only carried the compressed keys); rows of one segment all write the
-    # same value, the EMPTY segment keeps its init sentinel.
-    key_hi = (
-        jnp.full((C,), EMPTY_KEY_HI, jnp.uint32)
-        .at[state_seg].set(state.key_hi, mode="drop")
-        .at[batch_seg].set(ev_hi, mode="drop")
-    )
-    key_lo = (
-        jnp.full((C,), EMPTY_KEY_LO, jnp.uint32)
-        .at[state_seg].set(state.key_lo, mode="drop")
-        .at[batch_seg].set(ev_lo, mode="drop")
-    )
-    key_ws = (
-        jnp.full((C,), EMPTY_WS, jnp.int32)
-        .at[state_seg].set(state.key_ws, mode="drop")
-        .at[batch_seg].set(ev_ws, mode="drop")
-    )
-
-    zc = jnp.zeros((C,), jnp.int32)
+    u32, f32 = jnp.uint32, jnp.float32
+    bits = jax.lax.bitcast_convert_type
     one = ev_valid.astype(jnp.int32)
-    count = (
-        zc.at[state_seg].add(jnp.where(keep, state.count, 0), mode="drop")
-        .at[batch_seg].add(one, mode="drop")
-    )
+    inf = f32(jnp.inf)
 
-    # --- residual-anchor accumulation (the f64-free precision story) ----
-    # TPUs have no f64, and absolute f32 sums cannot hold the needed
-    # precision: Σlat over a 1M-event hot cell reaches ~4e7 where the f32
-    # ulp is 4, so even a correctly-rounded absolute sum puts the centroid
-    # ~2e-6 deg off.  Each group instead carries FIXED anchors (min over
-    # the events of the batch that created it — a segment-min, so both
-    # merge impls derive the identical value) and accumulates residuals
-    # about them.  Values within one hex cell lie within a fraction of
-    # each other, so `ev - anchor` is exact (Sterbenz) and the residual
-    # sums stay small enough for f32 to hold to ~1e-8 deg.  Consumers
-    # recombine anchor + resid/count in f64 host-side (sink/base.py,
-    # native/tile_ops.cpp); speed variance is anchor-invariant:
-    # Var(v) = E[r²] − E[r]².
-    inf = jnp.float32(jnp.inf)
+    with jax.named_scope("rewrite"):
+        # --- kept state rows: one gather through the inverse map ----------
+        src = (jnp.full((C,), C, jnp.int32)
+               .at[state_seg].set(jnp.arange(C, dtype=jnp.int32),
+                                  mode="drop"))
+        has_src = src < C
+        src = jnp.minimum(src, C - 1)
+        # every narrow lane rides ONE (C, 15) u32 row gather: a TPU gather
+        # pays per index (on a v5e at 2^21 rows, a gather per lane made
+        # this rebuild 2.7x slower)
+        rows = jnp.concatenate([
+            jnp.stack([bits(a, u32) for a in (
+                state.key_hi, state.key_lo, state.key_ws, state.count,
+                state.sum_speed, state.sum_speed2, state.sum_lat,
+                state.sum_lon, state.anchor_speed, state.anchor_lat,
+                state.anchor_lon)], axis=1),
+            bits(state.comp, u32),
+        ], axis=1)[src]
 
-    def group_anchor(ev, stored):
-        a = (jnp.full((C,), inf, jnp.float32)
-             .at[batch_seg].min(jnp.where(ev_valid, ev, inf), mode="drop"))
-        # existing groups keep their stored anchor: accumulated residuals
-        # are relative to it, so it must never move while the group lives
-        return a.at[state_seg].set(jnp.where(keep, stored, inf), mode="drop")
+        def kept(lanes, dtype, fill):
+            v = bits(rows[:, lanes], dtype)
+            return jnp.where(has_src[:, None] if v.ndim == 2 else has_src,
+                             v, fill)
 
-    anc_speed = group_anchor(ev_speed, state.anchor_speed)
-    anc_lat = group_anchor(ev_lat_deg, state.anchor_lat)
-    anc_lon = group_anchor(ev_lon_deg, state.anchor_lon)
+        def kept_sums(lanes):
+            # +0.0 where the scatter-add's 0.0 + v made a -0.0 positive
+            v = kept(lanes, f32, 0.0)
+            return jnp.where(v == 0, 0.0, v)
 
-    gi_ev = jnp.clip(batch_seg, 0, C - 1)
-    resid = lambda ev, anc: jnp.where(ev_valid, ev - anc[gi_ev], 0.0)
-    r_speed = resid(ev_speed, anc_speed)
-    r_lat = resid(ev_lat_deg, anc_lat)
-    r_lon = resid(ev_lon_deg, anc_lon)
-    # overflow-dropped events may read an empty row's inf anchor → non-
-    # finite residuals; their scatter writes are dropped (mode="drop"),
-    # so the values never land — only anchors stored/emitted must be
-    # sanitized (below).
+        # keys: the batch's rows of one segment all write the same value
+        # (the ORIGINAL arrays — the routing sort carried compressed keys)
+        key_hi = kept(0, u32, EMPTY_KEY_HI).at[batch_seg].set(
+            ev_hi, mode="drop")
+        key_lo = kept(1, u32, EMPTY_KEY_LO).at[batch_seg].set(
+            ev_lo, mode="drop")
+        key_ws = kept(2, jnp.int32, EMPTY_WS).at[batch_seg].set(
+            ev_ws, mode="drop")
+        count = kept(3, jnp.int32, 0).at[batch_seg].add(one, mode="drop")
+        base = kept_sums(slice(4, 8))
+        comp_r = kept_sums(slice(11, 15))
 
-    # the four float accumulators ride one (C, 4) scatter instead of four
-    kf = keep.astype(jnp.float32)
-    st_vals = jnp.stack([
-        state.sum_speed * kf, state.sum_speed2 * kf,
-        state.sum_lat * kf, state.sum_lon * kf,
-    ], axis=1)
-    ev_vals = jnp.stack([
-        r_speed, r_speed * r_speed, r_lat, r_lon,
-    ], axis=1)
-    base = jnp.zeros((C, 4), jnp.float32).at[state_seg].add(
-        st_vals, mode="drop")
-    delta = jnp.zeros((C, 4), jnp.float32).at[batch_seg].add(
-        ev_vals, mode="drop")
-    comp_r = jnp.zeros((C, 4), jnp.float32).at[state_seg].add(
-        state.comp * kf[:, None], mode="drop")
-    # Kahan fold of the batch delta into the carried sums: the error of
-    # each fold is captured in `comp` and fed back, so the accumulated
-    # error stays at per-batch scatter rounding instead of growing with
-    # the group's total count.  (XLA does not reassociate float adds by
-    # default, so the compensation term survives compilation.)
-    y = delta - comp_r
-    t = base + y
-    comp = (t - base) - y
-    sums = t
-    sum_speed, sum_speed2, sum_lat, sum_lon = (
-        sums[:, 0], sums[:, 1], sums[:, 2], sums[:, 3]
-    )
-    # empty/recycled rows: finite zeros (inf anchors would poison a later
-    # emit pack; empties have no batch events and no kept state row)
-    anc_speed = jnp.where(jnp.isfinite(anc_speed), anc_speed, 0.0)
-    anc_lat = jnp.where(jnp.isfinite(anc_lat), anc_lat, 0.0)
-    anc_lon = jnp.where(jnp.isfinite(anc_lon), anc_lon, 0.0)
+        # --- residual-anchor accumulation (the f64-free precision story) -
+        # TPUs have no f64, and absolute f32 sums cannot hold the needed
+        # precision: Σlat over a 1M-event hot cell reaches ~4e7 where the
+        # f32 ulp is 4, so even a correctly-rounded absolute sum puts the
+        # centroid ~2e-6 deg off.  Each group instead carries FIXED
+        # anchors (min over the events of the batch that created it — a
+        # segment-min, so both merge impls derive the identical value) and
+        # accumulates residuals about them.  Values within one hex cell
+        # lie within a fraction of each other, so `ev - anchor` is exact
+        # (Sterbenz) and the residual sums stay small enough for f32 to
+        # hold to ~1e-8 deg.  Consumers recombine anchor + resid/count in
+        # f64 host-side (sink/base.py, native/tile_ops.cpp); speed
+        # variance is anchor-invariant: Var(v) = E[r²] − E[r]².
+        def group_anchor(ev, lane):
+            a = (jnp.full((C,), inf, f32)
+                 .at[batch_seg].min(jnp.where(ev_valid, ev, inf),
+                                    mode="drop"))
+            # existing groups keep their stored anchor: accumulated
+            # residuals are relative to it, so it must never move while
+            # the group lives
+            return kept(lane, f32, a)
 
-    if B > 0:
-        bin_w = params.speed_hist_max / B
-        ev_bin = jnp.clip((ev_speed / bin_w).astype(jnp.int32), 0, B - 1)
-        hist = jnp.zeros((C, B), jnp.int32)
-        hist = hist.at[state_seg].add(
-            state.hist * keep[:, None].astype(jnp.int32), mode="drop"
+        anc_speed = group_anchor(ev_speed, 8)
+        anc_lat = group_anchor(ev_lat_deg, 9)
+        anc_lon = group_anchor(ev_lon_deg, 10)
+
+        gi_ev = jnp.clip(batch_seg, 0, C - 1)
+        resid = lambda ev, anc: jnp.where(ev_valid, ev - anc[gi_ev], 0.0)
+        r_speed = resid(ev_speed, anc_speed)
+        r_lat = resid(ev_lat_deg, anc_lat)
+        r_lon = resid(ev_lon_deg, anc_lon)
+        # overflow-dropped events may read an empty row's inf anchor →
+        # non-finite residuals; their scatter writes are dropped
+        # (mode="drop"), so the values never land — only anchors
+        # stored/emitted must be sanitized (below).
+
+        # the four float accumulators ride one (C, 4) scatter instead of
+        # four
+        ev_vals = jnp.stack([
+            r_speed, r_speed * r_speed, r_lat, r_lon,
+        ], axis=1)
+        delta = jnp.zeros((C, 4), f32).at[batch_seg].add(
+            ev_vals, mode="drop")
+        # Kahan fold of the batch delta into the carried sums: the error
+        # of each fold is captured in `comp` and fed back, so the
+        # accumulated error stays at per-batch scatter rounding instead of
+        # growing with the group's total count.  (XLA does not reassociate
+        # float adds by default, so the compensation term survives
+        # compilation.)
+        y = delta - comp_r
+        t = base + y
+        comp = (t - base) - y
+        sum_speed, sum_speed2, sum_lat, sum_lon = (
+            t[:, 0], t[:, 1], t[:, 2], t[:, 3]
         )
-        hist = hist.at[batch_seg, ev_bin].add(one, mode="drop")
-    else:
-        hist = state.hist
+        # empty/recycled rows: finite zeros (inf anchors would poison a
+        # later emit pack; empties have no batch events and no kept row)
+        anc_speed = jnp.where(jnp.isfinite(anc_speed), anc_speed, 0.0)
+        anc_lat = jnp.where(jnp.isfinite(anc_lat), anc_lat, 0.0)
+        anc_lon = jnp.where(jnp.isfinite(anc_lon), anc_lon, 0.0)
 
-    new_state = TileState(
-        key_hi=key_hi, key_lo=key_lo, key_ws=key_ws, count=count,
-        sum_speed=sum_speed, sum_speed2=sum_speed2,
-        sum_lat=sum_lat, sum_lon=sum_lon, hist=hist,
-        anchor_speed=anc_speed, anchor_lat=anc_lat, anchor_lon=anc_lon,
-        comp=comp,
-    )
+        if B > 0:
+            bin_w = params.speed_hist_max / B
+            ev_bin = jnp.clip((ev_speed / bin_w).astype(jnp.int32), 0,
+                              B - 1)
+            hist = (jnp.where(has_src[:, None], state.hist[src], 0)
+                    .at[batch_seg, ev_bin].add(one, mode="drop"))
+        else:
+            hist = state.hist
+
+        new_state = TileState(
+            key_hi=key_hi, key_lo=key_lo, key_ws=key_ws, count=count,
+            sum_speed=sum_speed, sum_speed2=sum_speed2,
+            sum_lat=sum_lat, sum_lon=sum_lon, hist=hist,
+            anchor_speed=anc_speed, anchor_lat=anc_lat,
+            anchor_lon=anc_lon, comp=comp,
+        )
 
     # --- update-mode emit: groups touched by this batch -------------------
     E = params.emit_capacity

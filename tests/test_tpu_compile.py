@@ -84,12 +84,15 @@ def test_pallas_snap_geometry_lowers(one_chip, res):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_aggregate_batch_backfill_shape(one_chip):
-    """synthetic_backfill's fold (res 9, batch 2^19, slab 2^20, the
-    default 64 speed bins) compiles and fits one chip."""
+@pytest.mark.parametrize("capacity", [1 << 20, 1 << 21])
+def test_aggregate_batch_backfill_shape(one_chip, capacity):
+    """synthetic_backfill's fold (res 9, batch 2^19, the default 64 speed
+    bins) compiles and fits one chip: on its configured 2^20-row slab
+    and on the 2^21 rows the runtime grows it to, where the slab rebuild
+    gathers kept rows through the inverse routing map."""
     from heatmap_tpu.engine.step import AggParams, aggregate_batch
 
-    batch, capacity = 1 << 19, 1 << 20
+    batch = 1 << 19
     params = AggParams(res=9, window_s=300, emit_capacity=batch)
     st = _state_shapes(capacity, 64, lambda a: one_chip)
     f32 = _shape((batch,), jnp.float32, one_chip)
