@@ -199,29 +199,35 @@ class MultiAggregator:
         when prekeys is given (a partial dict raises) — the pre-jitted
         _step_pre signature takes the full key tuple.
         """
-        if prekeys is not None:
-            missing = [r for r in self._uniq_res if r not in prekeys]
-            if missing:
-                raise ValueError(f"prekeys missing resolutions {missing}")
-            keys = tuple(
-                (self._put(prekeys[r][0]), self._put(prekeys[r][1]))
-                for r in self._uniq_res)
-            states, packed = self._step_pre(
-                tuple(self.states), keys,
-                self._put(lat_rad), self._put(lng_rad),
-                self._put(speed), self._put(ts), self._put(valid),
-                jnp.int32(watermark_cutoff),
-            )
-        else:
-            states, packed = self._step(
-                tuple(self.states),
-                self._put(lat_rad), self._put(lng_rad),
-                self._put(speed), self._put(ts), self._put(valid),
-                jnp.int32(watermark_cutoff),
-            )
+        fn, args = self._step_call(lat_rad, lng_rad, speed, ts, valid,
+                                   watermark_cutoff, prekeys)
+        states, packed = fn(*args)
         self.states = list(states)
         self.n_steps += 1
         return packed
+
+    def compile_step(self, lat_rad, lng_rad, speed, ts, valid,
+                     watermark_cutoff, prekeys=None) -> None:
+        """Compile, without running, the program ``step_packed_all``
+        runs on these arguments: the states are left as they are, and
+        the next step of that shape finds the program compiled."""
+        fn, args = self._step_call(lat_rad, lng_rad, speed, ts, valid,
+                                   watermark_cutoff, prekeys)
+        getattr(fn, "_inner", fn).lower(*args).compile()
+
+    def _step_call(self, lat_rad, lng_rad, speed, ts, valid,
+                   watermark_cutoff, prekeys):
+        """(jitted step, its arguments) of one batch."""
+        lanes = (self._put(lat_rad), self._put(lng_rad), self._put(speed),
+                 self._put(ts), self._put(valid), jnp.int32(watermark_cutoff))
+        if prekeys is None:
+            return self._step, (tuple(self.states),) + lanes
+        missing = [r for r in self._uniq_res if r not in prekeys]
+        if missing:
+            raise ValueError(f"prekeys missing resolutions {missing}")
+        keys = tuple((self._put(prekeys[r][0]), self._put(prekeys[r][1]))
+                     for r in self._uniq_res)
+        return self._step_pre, (tuple(self.states), keys) + lanes
 
     def view(self, res: int, window_s: int) -> "PairView":
         return PairView(self, self.pairs.index((res, window_s)))

@@ -1357,8 +1357,11 @@ class EmitRing:
             self.last_flush_live = []
         return entries
 
-    def flush_stacked(self, prefix: bool) -> list:
-        """Pull every parked batch in one transfer.
+    def flush_stacked(self, prefix: bool,
+                      max_depth: int | None = None) -> list:
+        """Pull every parked batch in one transfer, or in one transfer
+        per ``max_depth`` entries: each depth is a program of its own,
+        so a bound on it bounds the flush programs a ring can run.
 
         Returns [(bufs, tag)] in append order, where ``bufs`` is the
         per-pair list of host matrices ``pull_packed_stack`` would have
@@ -1366,15 +1369,23 @@ class EmitRing:
         stats_from_packed, packed_tile_docs) are unchanged.
         """
         entries = self.take()
-        if not entries:
-            return []
-        if len(entries) == 1:
-            packed, tag = entries[0]
-            return [(pull_packed_stack(packed, prefix), tag)]
-        import jax.numpy as jnp
+        step = max_depth or len(entries) or 1
+        out = []
+        for i in range(0, len(entries), step):
+            out += _pull_entries(entries[i:i + step], prefix)
+        return out
 
-        n_pairs = entries[0][0].shape[0]
-        blocks = jnp.concatenate([p for p, _ in entries], axis=0)
-        bufs = pull_packed_stack(blocks, prefix)
-        return [(bufs[i * n_pairs:(i + 1) * n_pairs], tag)
-                for i, (_, tag) in enumerate(entries)]
+
+def _pull_entries(entries: list, prefix: bool) -> list:
+    """``EmitRing.flush_stacked`` of the (packed, tag) ``entries``: one
+    concatenate on the device, one ``pull_packed_stack``."""
+    if len(entries) == 1:
+        packed, tag = entries[0]
+        return [(pull_packed_stack(packed, prefix), tag)]
+    import jax.numpy as jnp
+
+    n_pairs = entries[0][0].shape[0]
+    blocks = jnp.concatenate([p for p, _ in entries], axis=0)
+    bufs = pull_packed_stack(blocks, prefix)
+    return [(bufs[i * n_pairs:(i + 1) * n_pairs], tag)
+            for i, (_, tag) in enumerate(entries)]

@@ -927,7 +927,7 @@ class MicroBatchRuntime:
                         compile_tracker=self.runtimeinfo.compile,
                         memory=self.runtimeinfo.memory, shard=d))
                 self.runtimeinfo.compile.warmup += len(govs[0].ladder)
-                self._warm_mesh_ladder(govs[0].ladder)
+                self._warm_mesh_ladder(govs[0].ladder, govs[0].flush_k_max)
                 for gov in govs:
                     gov._retrace_base = gov._retraces()
                 self._mesh_governors = govs
@@ -961,7 +961,8 @@ class MicroBatchRuntime:
         if self._parted is not None and self._mesh_governors is None:
             # the ungoverned mesh warms its one bucket the same way
             self.runtimeinfo.compile.warmup += 1
-            self._warm_mesh_ladder((self._feed_batch,))
+            self._warm_mesh_ladder((self._feed_batch,),
+                                   self._mesh_rings[0].capacity)
         # offsets as of the last DISPATCHED batch: checkpoints commit these,
         # never the live source offsets, so a batch polled but not yet
         # dispatched (exception between poll and dispatch) always replays
@@ -2024,6 +2025,8 @@ class MicroBatchRuntime:
             return  # at the ceiling; the overflow accounting stands guard
         t0 = time.monotonic()
         agg.grow(new_cap)
+        if self._parted is not None:
+            self._on_mesh_devices(self._compile_mesh_step)
         self.metrics.count("state_grown")
         self.metrics.counters["state_capacity_per_shard"] = new_cap
         self._g_capacity.set(new_cap)
@@ -2537,6 +2540,12 @@ class MicroBatchRuntime:
                     self._mesh_rings[d].append(packed, self.epoch,
                                                live=ch["n"] > 0)
                     n_entries += 1
+                    # per-shard rows and padding, in the counters a
+                    # window's deltas are read from (partition skew and
+                    # the pad bucket's waste)
+                    self.metrics.count(f"mesh_rows.{d}", ch["n"])
+                    self.metrics.count(f"mesh_pad_rows.{d}",
+                                       f["valid"].shape[0] - ch["n"])
                     if ch["n"]:
                         self._mesh_rows[d] += ch["n"]
                         self._c_mesh_rows.labels(shard=str(d)).inc(
@@ -2761,39 +2770,80 @@ class MicroBatchRuntime:
                 "n": 0, "feed": feed, "prekeys": prekeys}
         return cached
 
-    def _warm_mesh_ladder(self, ladder) -> None:
+    def _warm_mesh_ladder(self, ladder, flush_k: int) -> None:
         """Precompile every device's fused step at every pad bucket of
         ``ladder`` (the single-device _warm_ladder, per mesh shard): one
         all-invalid dispatch per (device, bucket) through the
         instrumented entry points — identity on the state, results
-        discarded.  Each device runs its own copy of the program, a
-        compile of its own, so the devices compile in parallel, one
-        thread each; the step loop would compile them one after
-        another.  After this a governed bucket move on ANY shard is a
-        pure cache hit; any later compile IS a retrace and freezes
+        discarded — and then the device's ring flushes
+        (_warm_mesh_flushes).  Each device runs its own copy of every
+        program, a compile of its own, so the devices compile in
+        parallel, one thread each; the step loop would compile them one
+        after another.  After this a governed bucket move on ANY shard
+        is a pure cache hit; any later compile IS a retrace and freezes
         every shard governor (the per-ladder latch)."""
-        from concurrent.futures import ThreadPoolExecutor
-
         def warm(d: int) -> None:
             for n_rows in ladder:
                 ch = self._mesh_idle_chunk(d, bucket=n_rows)
                 f = ch["feed"]
-                self._parted.step_shard(
+                packed = self._parted.step_shard(
                     d, f["lat"], f["lng"], f["speed"], f["ts"],
                     f["valid"], I32_MIN, prekeys=ch["prekeys"])
+            self._warm_mesh_flushes(d, packed, flush_k)
 
         t0 = time.monotonic()
-        with ThreadPoolExecutor(self._parted.n_shards) as ex:
-            list(ex.map(warm, range(self._parted.n_shards)))
-        log.info("mesh governor bucket ladder warmed on %d devices: %s "
-                 "(%.2fs)", self._parted.n_shards, ladder,
+        self._on_mesh_devices(warm)
+        log.info("mesh governor bucket ladder and ring flushes warmed on "
+                 "%d devices: %s (%.2fs)", self._parted.n_shards, ladder,
                  time.monotonic() - t0)
+
+    def _on_mesh_devices(self, fn) -> None:
+        """``fn(d)`` for every mesh device, one thread each: a device's
+        programs are compiles of their own, and threads run them side
+        by side where the step loop would run them one after another."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(self._parted.n_shards) as ex:
+            list(ex.map(fn, range(self._parted.n_shards)))
+
+    def _compile_mesh_step(self, d: int) -> None:
+        """Compile, without running, device ``d``'s step at its current
+        pad bucket (after a slab growth, before the next dispatch)."""
+        ch = self._mesh_idle_chunk(d)
+        f = ch["feed"]
+        self._parted.shards[d].compile_step(
+            f["lat"], f["lng"], f["speed"], f["ts"], f["valid"], I32_MIN,
+            prekeys=ch["prekeys"])
+
+    def _warm_mesh_flushes(self, d: int, packed, flush_k: int) -> None:
+        """Compile device ``d``'s emit ring flush for every shape it can
+        take: each depth up to ``flush_k`` (a mesh flush pulls at most
+        the ring's capacity per transfer, _pull_mesh_ring) and, with
+        the live-prefix pull, each power-of-two bucket of emitted rows
+        up to the emit capacity.  Each is a program of its own, on each
+        device.  ``packed`` is one of the device's step outputs: the
+        flushes run on arrays of its shape, placed as it is."""
+        from heatmap_tpu.engine.step import EmitRing
+
+        emit_cap = packed.shape[1] - 1
+        buckets = [1 << b for b in range(emit_cap.bit_length())
+                   if (1 << b) < emit_cap] + [emit_cap]
+        host = np.zeros(packed.shape, packed.dtype)
+        for bucket in buckets if self._prefix_pull else buckets[:1]:
+            host[:, 0, 0] = bucket   # head row: rows emitted by the batch
+            dummy = jax.device_put(host, self._parted.devices[d])
+            for k in range(1, flush_k + 1):
+                ring = EmitRing(k)
+                for _ in range(k):
+                    ring.append(dummy)
+                self._pull_mesh_ring(ring)
 
     def _flush_mesh_shard(self, d: int) -> None:
         """Pull + account every batch parked on ONE mesh shard's device
-        (partitioned mode).  One call = one stacked transfer off that
-        device ONLY — a hot downtown shard flushing at its own cadence
-        never forces a pull on three idle suburb shards, so idle
+        (partitioned mode).  One call pulls off that device ONLY, in
+        one stacked transfer per ring capacity of parked entries — a
+        hot downtown shard flushing at its own cadence never forces a
+        pull on three idle suburb shards, so idle
         shards' pull counts stay at the idle-flush floor (checkpoints,
         idle polls, close)."""
         ring = self._mesh_rings[d]
@@ -2808,7 +2858,7 @@ class MicroBatchRuntime:
         from heatmap_tpu.engine.multi import stats_from_packed
 
         n_batches = len(ring)
-        flushed = ring.flush_stacked(self._prefix_pull)
+        flushed = self._pull_mesh_ring(ring)
         residency = ring.last_flush_residency
         live = ring.last_flush_live
         batch_max = I32_MIN
@@ -2840,6 +2890,13 @@ class MicroBatchRuntime:
         self._last_flush_cutoff = (
             self.max_event_ts - self.cfg.watermark_minutes * 60
             if self.max_event_ts > I32_MIN else I32_MIN)
+
+    def _pull_mesh_ring(self, ring) -> list:
+        """Every entry parked in a mesh ring, pulled in transfers of at
+        most the ring's capacity: idle entries park up to 8x that deep,
+        and each depth is a program of its own, which
+        _warm_mesh_flushes compiles up to the capacity."""
+        return ring.flush_stacked(self._prefix_pull, max_depth=ring.capacity)
 
     def _note_mesh_flushed(self, epoch: int, residency) -> None:
         """Per-(shard, batch) flush accounting on the partitioned mesh:

@@ -92,6 +92,33 @@ def test_emitring_stacked_flush_equals_per_batch_pull():
             assert isinstance(stats_from_packed(bufs[0]), MultiStats)
 
 
+def test_emitring_flush_in_transfers_of_max_depth():
+    """``max_depth`` splits a deep flush into stacked transfers of at
+    most that many entries (each depth is a program of its own): the
+    batches come back in append order with the content of one
+    transfer."""
+    from heatmap_tpu.engine.step import EmitRing
+
+    rng = np.random.default_rng(3)
+    packs = [rng.integers(0, 2**32, (2, 9, 13), dtype=np.uint32)
+             for _ in range(5)]
+    for k, p in enumerate(packs):
+        p[:, 0, 0] = k + 1       # head row: rows emitted by the batch
+    for prefix in (False, True):
+        whole, split = EmitRing(8), EmitRing(8)
+        for i, p in enumerate(packs):
+            whole.append(p, tag=i)
+            split.append(p, tag=i)
+        ref = whole.flush_stacked(prefix)
+        got = split.flush_stacked(prefix, max_depth=2)
+        assert [t for _, t in got] == [t for _, t in ref] == list(range(5))
+        assert len(split) == 0 and split.n_flushes == 1
+        for (bufs, _), (ref_bufs, _), p in zip(got, ref, packs):
+            n = int(p[0, 0, 0])
+            for b, r in zip(bufs, ref_bufs):
+                np.testing.assert_array_equal(b[:n + 1], r[:n + 1])
+
+
 def test_emitring_refuses_shape_change():
     """A slab/emit-capacity resize mid-interval would corrupt the stack;
     append must refuse loudly (the runtime flushes before every grow)."""

@@ -422,3 +422,127 @@ def test_partitioned_resume_refuses_in_program_keyed_checkpoint(
     with pytest.raises(RuntimeError, match="partitioned"):
         run_one(tmp_path, events, "xla-keyed", mesh=mesh,
                 checkpoint_every=1)
+
+
+def _compile_counter():
+    """Count XLA compile requests (compiled or read from the persistent
+    cache) in this process from now on."""
+    import jax
+
+    box = {"n": 0}
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            box["n"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    return box
+
+
+def test_mesh_shard_counters_sum_to_rows_and_dispatch(tmp_path):
+    """The per-shard window counters: rows sum to every event the feed
+    handed the fold (valid and late; the invalid never leave the host),
+    and rows plus padding to the rows dispatched, one pad bucket per
+    shard entry."""
+    events = mk_stream()
+    rt, _ = run_one(tmp_path, events, "count", mesh=make_mesh(N_DEV))
+    c = rt.metrics.counters
+    rows = [c[f"mesh_rows.{d}"] for d in range(N_DEV)]
+    pads = [c[f"mesh_pad_rows.{d}"] for d in range(N_DEV)]
+    assert sum(rows) == c["events_valid"] + c["events_late"] \
+        == len(events) - c["events_invalid"]
+    assert rows == [m["rows"] for m in rt.mesh_shard_stats()]
+    entries = sum(m["emit_pull_batches"] for m in rt.mesh_shard_stats())
+    assert sum(rows) + sum(pads) == entries * BATCH
+    assert all(p > 0 for p in pads)
+
+
+@pytest.mark.parametrize("emit_pull", ["prefix", "full"])
+def test_mesh_ring_flush_compiles_nothing_after_start_up(tmp_path,
+                                                         emit_pull):
+    """After start-up, a flush of each mesh ring at every depth it can
+    reach (idle entries park up to 8x its capacity) and, under the
+    prefix pull, every emitted-rows bucket, reads programs the runtime
+    compiled at start-up: no compile request."""
+    import jax
+
+    cfg = load_config(
+        {}, batch_size=BATCH, state_capacity_log2=12, speed_hist_bins=8,
+        store="memory", emit_flush_k=3, emit_pull=emit_pull,
+        checkpoint_dir=str(tmp_path / "ckpt"))
+    rt = MicroBatchRuntime(cfg, MemorySource([]), MemoryStore(),
+                           mesh=make_mesh(N_DEV), checkpoint_every=0)
+    from heatmap_tpu.engine.step import EmitRing
+
+    parked = []
+    for d in range(N_DEV):
+        ch = rt._mesh_idle_chunk(d)
+        f = ch["feed"]
+        packed = rt._parted.step_shard(d, f["lat"], f["lng"], f["speed"],
+                                       f["ts"], f["valid"], -(2**31),
+                                       prekeys=ch["prekeys"])
+        host = np.asarray(packed)
+        emit_cap = host.shape[1] - 1
+        for n in sorted({1, 3, 17, emit_cap // 2 + 1, emit_cap}):
+            host = host.copy()
+            host[:, 0, 0] = n
+            parked.append((d, jax.device_put(host, packed.sharding)))
+    cap = rt._mesh_rings[0].capacity
+    compiles = _compile_counter()
+    for d, entry in parked:
+        for depth in range(1, 8 * cap + 1):
+            ring = EmitRing(cap)
+            for _ in range(depth):
+                ring.append(entry, live=False)
+            assert len(rt._pull_mesh_ring(ring)) == depth
+    assert compiles["n"] == 0
+
+
+def test_mesh_slab_growth_compiles_every_device_before_the_step(
+        tmp_path, monkeypatch):
+    """A slab growth on the partitioned mesh compiles every device's
+    grown step at once, in threads, without running it: the dispatch
+    after it compiles nothing, and the results are the one-device
+    fold's."""
+    from heatmap_tpu.stream import runtime as rtmod
+
+    events = mk_stream()
+    compiles = _compile_counter()
+    grown = []
+    grow = rtmod.MicroBatchRuntime._maybe_grow
+
+    def spy(self):
+        cap, n0 = self._agg().capacity_per_shard, compiles["n"]
+        grow(self)
+        if self._agg().capacity_per_shard != cap:
+            grown.append(compiles["n"] - n0)
+
+    dispatch = rtmod.MicroBatchRuntime._dispatch
+    after = []
+
+    def spy_dispatch(self, entry, cutoff):
+        n0 = compiles["n"]
+        dispatch(self, entry, cutoff)
+        if grown and len(after) < len(grown):
+            after.append(compiles["n"] - n0)
+
+    def run(tag, mesh=None):
+        cfg = load_config(
+            {}, batch_size=BATCH, state_capacity_log2=7, speed_hist_bins=8,
+            store="memory", emit_flush_k=3,
+            checkpoint_dir=str(tmp_path / f"ckpt-{tag}"))
+        source = MemorySource(copy.deepcopy(events))
+        source.finish()
+        store = MemoryStore()
+        MicroBatchRuntime(cfg, source, store, mesh=mesh,
+                          checkpoint_every=0).run()
+        return store
+
+    with monkeypatch.context() as m:
+        m.setattr(rtmod.MicroBatchRuntime, "_maybe_grow", spy)
+        m.setattr(rtmod.MicroBatchRuntime, "_dispatch", spy_dispatch)
+        sN = run("grow", make_mesh(N_DEV))
+    s1 = run("grow1")
+    assert grown and all(n == N_DEV for n in grown), grown
+    assert after == [0] * len(grown)
+    assert_stores_equal(s1, sN)
