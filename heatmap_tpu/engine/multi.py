@@ -218,8 +218,10 @@ class MultiAggregator:
     def _step_call(self, lat_rad, lng_rad, speed, ts, valid,
                    watermark_cutoff, prekeys):
         """(jitted step, its arguments) of one batch."""
+        # committed, or each shard's step waits in the default device's queue
         lanes = (self._put(lat_rad), self._put(lng_rad), self._put(speed),
-                 self._put(ts), self._put(valid), jnp.int32(watermark_cutoff))
+                 self._put(ts), self._put(valid),
+                 self._put(np.int32(watermark_cutoff)))
         if prekeys is None:
             return self._step, (tuple(self.states),) + lanes
         missing = [r for r in self._uniq_res if r not in prekeys]

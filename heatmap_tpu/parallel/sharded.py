@@ -660,10 +660,10 @@ class PartitionedAggregator:
     weight: every device already holds exactly its own cell space.  This
     class therefore runs one fused single-device program
     (engine.multi.MultiAggregator) per mesh device, inputs committed to
-    that device — no collectives, no lockstep, no shared dispatch
-    stream.  Dispatches are async, so the per-device folds overlap; each
-    device's packed emits stay resident on ITS chip, which is what lets
-    the runtime keep one independently-flushed EmitRing and one
+    that device (the watermark cutoff too) — no collectives, no
+    lockstep, no shared dispatch stream, so the async per-device folds
+    overlap; each device's packed emits stay resident on ITS chip, which
+    lets the runtime keep one independently-flushed EmitRing and one
     independently-governed BatchGovernor per shard (the mesh-resident
     fast path).
 
