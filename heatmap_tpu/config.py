@@ -14,6 +14,23 @@ import os
 from typing import Mapping, Sequence
 
 
+# Variables of the removed multi-process JAX mesh: refused when set, so a
+# pod started the old way fails at start-up instead of running each host
+# as an independent runtime.
+REMOVED_MULTI_HOST_ENV = ("HEATMAP_COORDINATOR", "HEATMAP_NUM_PROCESSES",
+                          "HEATMAP_PROCESS_ID")
+
+# H3 children are not contained in their parents: rows partitioned by one
+# resolution's cells split some cells of the other resolutions over two
+# folds, whose groups would then be counted in part.  Raised for the
+# process fleet here and for the mesh by parallel.PartitionedAggregator.
+MULTI_RES_REFUSAL = (
+    "a multi-resolution pyramid (H3_RESOLUTIONS {res}) cannot be folded "
+    "exactly on {what}: the H3-cell partition splits the cells of its "
+    "other resolutions over two folds.  Fold the pyramid on one device "
+    "(NUM_SHARDS=1, HEATMAP_SHARDS=1), or run one runtime per resolution")
+
+
 def _int(env: Mapping[str, str], name: str, default: int) -> int:
     return int(env.get(name, default))
 
@@ -61,7 +78,6 @@ class Config:
     speed_hist_bins: int = 64
     speed_hist_max_kmh: float = 256.0
     num_shards: int = 0                # 0 = use all local devices
-    bucket_factor: float = 2.0         # all_to_all lane skew tolerance
     trigger_ms: int = 0                # 0 = as fast as possible (ref default)
     on_overflow: str = "error"         # "error": metric + rate-limited log;
                                        # "fail": stop the run (data loss is
@@ -108,15 +124,12 @@ class Config:
                                        # sink semantics and replay
                                        # equivalence are unchanged.  1 =
                                        # per-batch pull (the pre-ring
-                                       # behavior); multi-host runs force
-                                       # 1 (lockstep accounting).
+                                       # behavior).
     prefetch_batches: int = 1          # HEATMAP_PREFETCH_BATCHES: batches
                                        # the runtime polls/pads/transfers
                                        # AHEAD of the fold so the H2D feed
                                        # overlaps device compute (double
-                                       # buffering).  0 disables; multi-
-                                       # host runs force 0 (the lockstep
-                                       # collectives pin poll ordering).
+                                       # buffering).  0 disables.
     flightrec_dir: str = ""            # HEATMAP_FLIGHTREC_DIR: directory
                                        # for post-mortem flight records
                                        # (obs.flightrec) — on abnormal
@@ -335,22 +348,11 @@ class Config:
                                        # (throughput) moves while the
                                        # recent event-age p50 is below
                                        # this fraction of the SLO
-    mesh_partitioned: str = "auto"     # HEATMAP_MESH_PARTITIONED: mesh
-                                       # execution mode when a
-                                       # multi-device mesh is attached.
-                                       # "auto" (default) = the
-                                       # shard-per-device PARTITIONED
-                                       # fast path on single-process
-                                       # meshes (feed pre-partitions
-                                       # each batch by H3 parent cell,
-                                       # every device runs the fused
-                                       # fold collective-free with its
-                                       # own emit ring and governor);
-                                       # multi-host meshes always keep
-                                       # the ICI-shuffle lockstep path.
-                                       # "1" forces partitioned (warns
-                                       # and falls back on multi-host),
-                                       # "0" forces the shuffle path.
+    mesh_partitioned: str = "auto"     # "auto" | "1": both mean the
+                                       # partitioned mesh, the only
+                                       # mesh path; no env var reads
+                                       # it.  Kept as a field so
+                                       # configs that still set it load.
     audit: bool = False                # HEATMAP_AUDIT: the integrity
                                        # observatory (obs/audit.py) —
                                        # observe-only event-conservation
@@ -551,6 +553,14 @@ class Config:
 def load_config(env: Mapping[str, str] | None = None, **overrides) -> Config:
     """Build a Config from env vars (same names as the reference) + overrides."""
     e = dict(os.environ if env is None else env)
+    gone = [k for k in REMOVED_MULTI_HOST_ENV if e.get(k, "") != ""]
+    if gone:
+        raise ValueError(
+            f"{', '.join(gone)}: the multi-process JAX mesh was removed, "
+            f"and a runtime started this way would fold only its own "
+            f"host's share into the shared store.  Run one runtime per "
+            f"host, split by HEATMAP_SHARDS/HEATMAP_SHARD_INDEX, each over "
+            f"its own chips, and unset these variables")
     cfg = Config(
         mongo_uri=e.get("MONGO_URI", Config.mongo_uri),
         mongo_db=e.get("MONGO_DB", Config.mongo_db),
@@ -573,7 +583,6 @@ def load_config(env: Mapping[str, str] | None = None, **overrides) -> Config:
         speed_hist_bins=_int(e, "SPEED_HIST_BINS", Config.speed_hist_bins),
         speed_hist_max_kmh=_float(e, "SPEED_HIST_MAX_KMH", Config.speed_hist_max_kmh),
         num_shards=_int(e, "NUM_SHARDS", Config.num_shards),
-        bucket_factor=_float(e, "EXCHANGE_BUCKET_FACTOR", Config.bucket_factor),
         trigger_ms=_int(e, "TRIGGER_MS", Config.trigger_ms),
         on_overflow=e.get("HEATMAP_ON_OVERFLOW", Config.on_overflow),
         serve_host=e.get("SERVE_HOST", Config.serve_host),
@@ -683,8 +692,6 @@ def load_config(env: Mapping[str, str] | None = None, **overrides) -> Config:
         audit=e.get("HEATMAP_AUDIT", "0") not in ("0", "false", ""),
         audit_settle_s=_float(e, "HEATMAP_AUDIT_SETTLE_S",
                               Config.audit_settle_s),
-        mesh_partitioned=e.get("HEATMAP_MESH_PARTITIONED",
-                               Config.mesh_partitioned),
     )
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
@@ -789,6 +796,10 @@ def load_config(env: Mapping[str, str] | None = None, **overrides) -> Config:
             f"HEATMAP_SHARD_INDEX must be in 0..{cfg.shards - 1}, "
             f"got {cfg.shard_index}")
     if cfg.shards > 1:
+        if len(set(cfg.resolutions)) > 1:
+            raise ValueError(MULTI_RES_REFUSAL.format(
+                what=f"a {cfg.shards}-process HEATMAP_SHARDS fleet",
+                res=sorted(set(cfg.resolutions))))
         snap_res = min(cfg.resolutions)
         if not (cfg.shard_res == -1 or 0 <= cfg.shard_res <= snap_res):
             raise ValueError(
@@ -819,10 +830,11 @@ def load_config(env: Mapping[str, str] | None = None, **overrides) -> Config:
         raise ValueError(
             f"HEATMAP_GOVERN_HEALTHY_FRAC must be in (0, 1), "
             f"got {cfg.govern_healthy_frac}")
-    if cfg.mesh_partitioned not in ("auto", "0", "1"):
+    if cfg.mesh_partitioned not in ("auto", "1"):
         raise ValueError(
-            f"HEATMAP_MESH_PARTITIONED must be auto|0|1, "
-            f"got {cfg.mesh_partitioned!r}")
+            f"mesh_partitioned must be auto|1, got "
+            f"{cfg.mesh_partitioned!r}: the ICI-shuffle mesh (\"0\") was "
+            f"removed; every mesh runs partitioned by H3 cell")
     if not 0 <= cfg.shard_oversample <= 64:
         raise ValueError(
             f"HEATMAP_SHARD_OVERSAMPLE must be in 0..64, "

@@ -99,7 +99,7 @@ class MultiAggregator:
 
     ``device``: optional explicit jax device this aggregator's state and
     feeds are committed to.  The partitioned mesh fast path
-    (parallel.sharded.PartitionedAggregator) runs one MultiAggregator
+    (parallel.PartitionedAggregator) runs one MultiAggregator
     per mesh device this way — jit follows the committed inputs, so each
     shard's program executes on its own chip with no collectives and no
     shared dispatch stream.  ``None`` (the default) keeps the historical

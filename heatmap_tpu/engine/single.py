@@ -1,7 +1,7 @@
-"""Single-device aggregator with the same host API as parallel.ShardedAggregator.
+"""Single-device, single-pair aggregator.
 
-Used when one chip is enough (the bench's single-chip runs) — skips the
-all_to_all exchange entirely; the state slab lives on the default device.
+Used when one chip is enough (the bench's single-chip runs); the state
+slab lives on the default device.
 """
 
 from __future__ import annotations
@@ -84,9 +84,8 @@ class SingleAggregator:
         """Like step_packed, but the StepStats ride the packed head row
         (engine.step.ride_stats) so the WHOLE batch output is one device
         array — the shape engine.step.EmitRing accumulates and
-        ``stats_from_packed`` decodes (parity with MultiAggregator /
-        ShardedAggregator).  Returns the (E+1, 13) packed matrix on
-        device."""
+        ``stats_from_packed`` decodes (parity with MultiAggregator).
+        Returns the (E+1, 13) packed matrix on device."""
         self.state, packed = self._step_ride(
             self.state,
             jnp.asarray(lat_rad), jnp.asarray(lng_rad), jnp.asarray(speed),
@@ -96,7 +95,7 @@ class SingleAggregator:
         return packed
 
     def emit_to_host(self, emit) -> dict:
-        """Emit leaves as host numpy (API parity with ShardedAggregator)."""
+        """Emit leaves as host numpy."""
         import numpy as np
 
         e = jax.device_get(emit)

@@ -84,13 +84,9 @@ SNAP_IMPL: "str | None" = None
 # merge_batch users (bench, tests, notebooks).  The stream runtime
 # REPLACES it at init with a one-shot snapshot (a winner name or None),
 # because (a) re-reading the bank at every trace would let a bank file
-# rewritten MID-RUN flip the impl after the multihost startup collective
-# validated a snapshot, compiling divergent lockstep programs across
-# hosts, and
+# rewritten MID-RUN flip the impl at a slab-growth retrace, and
 # (b) the getmtime stat has no place on the per-batch hot path.  The
-# collective demotes the snapshot to None when hosts' banks disagree
-# (every host then shares the static capacity-ratio rule; the merge
-# impls are bit-identical, so results never depend on the choice).
+# merge impls are bit-identical, so results never depend on the choice.
 _BANK_LIVE = object()
 MERGE_BANK_PIN: "str | None | object" = _BANK_LIVE
 
@@ -1087,7 +1083,7 @@ def pack_emit(emit: BatchEmit, speed_hist_max: float = 256.0) -> jnp.ndarray:
     matrix makes the per-batch pull a single transfer.
     Row 0 carries [n_emitted, overflowed] in slots 0..1; slots 2.. are
     reserved for a stats rider (``ride_stats`` — engine.multi and
-    parallel.sharded embed their step stats there so the host needs no
+    engine.single embed their step stats there so the host needs no
     second transfer).  Rows 1.. are [key_hi, key_lo, ws, count, sum_speed,
     sum_speed2, sum_lat, sum_lon, valid, p95, anchor_speed, anchor_lat,
     anchor_lon] with float lanes bitcast — the sum lanes are per-group

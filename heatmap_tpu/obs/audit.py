@@ -14,7 +14,7 @@ gated by ``HEATMAP_AUDIT=1`` (zero data-path mutation either way):
 runtime stamps at every pipeline boundary):
 
     polled == folded + dropped{reason: invalid, late, out_of_shard,
-                               oversample, exchange}
+                               oversample}
     docs_emitted == docs_committed == docs_view_applied
     view seq == repl feed seq == replica applied seq   (per replica)
 

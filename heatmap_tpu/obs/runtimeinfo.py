@@ -17,7 +17,7 @@ exists for:
   record of the high-water trajectory.
 
 ``CompileTracker`` wraps the jitted entry points (engine.multi /
-parallel.sharded step functions) and detects compiles by probing the
+parallel.partitioned step functions) and detects compiles by probing the
 jit cache size around each call — no global monkeypatching, and the
 probe is two attribute reads per step.  A compile observed after a
 function's warmup (``HEATMAP_WARMUP_BATCHES`` calls, default 4) is a

@@ -59,8 +59,7 @@ class TraceRing:
 
     def record(self, epoch: int, latency_s: float, spans: dict,
                n_events: int = 0, n_late: int = 0,
-               overflow_groups: int = 0, late_dropped: int = 0,
-               **extra) -> dict:
+               overflow_groups: int = 0, **extra) -> dict:
         rec = {
             "seq": 0,  # filled under the lock
             "epoch": int(epoch),
@@ -70,7 +69,6 @@ class TraceRing:
             "n_events": int(n_events),
             "n_late": int(n_late),
             "overflow_groups": int(overflow_groups),
-            "late_dropped": int(late_dropped),
         }
         rec.update(extra)
         with self._lock:

@@ -1,20 +1,15 @@
-"""parallel — multi-chip sharded aggregation over a jax.sharding.Mesh.
+"""parallel — the partitioned multi-chip mesh.
 
 The reference brings all events of one (cellId, window) group together with a
 hash-partitioned JVM shuffle across Spark tasks (reference:
 heatmap_stream.py:44 ``spark.sql.shuffle.partitions=4``, :112-117 groupBy).
-Here the same routing runs over TPU ICI: every device owns the slice of key
-space ``hash(key) % n_shards``, a ``shard_map`` step snaps its local batch
-shard, exchanges events to their key owners with one ``all_to_all``
-collective, and folds the received events into its local sorted state slab
-(engine.merge_batch).  Keys are therefore unique across shards, so emits
-need no cross-shard dedup, and scalar stats ride a ``psum``/``pmax``.
+Here the feed partitions each batch by H3 cell instead, so every group's
+events already share one device: each device of a ``jax.sharding.Mesh``
+folds its own cell space with the single-device program, and no collective
+runs between them.
 """
 
-from heatmap_tpu.parallel import multihost  # noqa: F401
-from heatmap_tpu.parallel.sharded import (  # noqa: F401
+from heatmap_tpu.parallel.partitioned import (  # noqa: F401
     PartitionedAggregator,
-    ShardedAggregator,
-    ShardStats,
     make_mesh,
 )

@@ -430,20 +430,6 @@ def healthz_payload(runtime, extra_checks=None) -> tuple[dict, bool]:
                 checks["event_age_p50_ms"] = {"value": round(p50_ms, 3),
                                               "budget": budget, "ok": ok}
                 degraded |= not ok
-        pinned = getattr(runtime, "_fastpath_pinned", None)
-        if pinned:
-            # satellite bugfix (ISSUE 11): a runtime that silently
-            # pinned its fast-path knobs down (multi-host forcing
-            # emit_flush_k=1/prefetch=0, a governor request the
-            # topology can't honor) surfaces the pin here as a WARNING
-            # — visible in the checks payload without degrading the
-            # verdict (the pin is intended behavior for its topology,
-            # but an operator expecting the ring must be able to see
-            # it was lost)
-            checks["fastpath_pinned"] = {
-                "value": "; ".join(f"{k}: {v}"
-                                   for k, v in sorted(pinned.items())),
-                "ok": True, "warn": True}
         gov = getattr(runtime, "governor", None)
         if gov is not None:
             # adaptive micro-batching guardrail (stream/govern.py): a
@@ -999,7 +985,8 @@ def make_wsgi_app(store: Store, cfg=None, runtime=None):
     # The materialized tile view (query.matview) serving /latest renders,
     # ETags, deltas, SSE, and topk without touching the Store:
     # - runtime attached: the runtime's writer-fed view (durable rows
-    #   only; absent under HEATMAP_QUERY_VIEW=0 or multi-host).
+    #   only; absent under HEATMAP_QUERY_VIEW=0 or on a HEATMAP_SHARDS
+    #   shard).
     # - serve-only: an app-local view rebuilt from Store scans by
     #   version polling + the HEATMAP_VIEW_POLL_MS TTL.
     from heatmap_tpu.obs.registry import Registry
@@ -1070,7 +1057,7 @@ def make_wsgi_app(store: Store, cfg=None, runtime=None):
 
             view_audit = DigestTable()
         # registry unconditionally: a runtime WITHOUT a writer-fed view
-        # (multi-host) still lands here, and its operators need the
+        # (a HEATMAP_SHARDS shard) still lands here, and its operators need the
         # documented view series; registration is idempotent, and when
         # this branch runs the runtime never registered them itself
         view = TileMatView(

@@ -39,10 +39,7 @@ class CheckpointManager:
         os.makedirs(directory, exist_ok=True)
         self.latest_path = os.path.join(directory, "LATEST")
 
-    def _commit_dir(self, epoch: int | None = None) -> str | None:
-        if epoch is not None:
-            path = os.path.join(self.dir, f"commit-{epoch:012d}")
-            return path if os.path.isdir(path) else None
+    def _commit_dir(self) -> str | None:
         if not os.path.exists(self.latest_path):
             return None
         with open(self.latest_path, encoding="utf-8") as fh:
@@ -50,28 +47,17 @@ class CheckpointManager:
         path = os.path.join(self.dir, name)
         return path if os.path.isdir(path) else None
 
-    def available_epochs(self) -> list[int]:
-        """Epochs with a complete retained commit dir (ascending)."""
-        out = []
-        for n in sorted(os.listdir(self.dir)) if os.path.isdir(self.dir) else []:
-            if n.startswith("commit-") and not n.endswith(".tmp"):
-                if os.path.exists(os.path.join(self.dir, n, "meta.json")):
-                    out.append(int(n[len("commit-"):]))
-        return out
-
     # --- read -----------------------------------------------------------
-    def load_meta(self, epoch: int | None = None) -> dict | None:
-        """Latest commit's meta, or a specific retained epoch's (multi-host
-        resume agreement loads the common min epoch — stream.runtime)."""
-        d = self._commit_dir(epoch)
+    def load_meta(self) -> dict | None:
+        """Latest commit's meta."""
+        d = self._commit_dir()
         if d is None:
             return None
         with open(os.path.join(d, "meta.json"), encoding="utf-8") as fh:
             return json.load(fh)
 
-    def load_state(self, res: int, win: int,
-                   epoch: int | None = None) -> TileState | None:
-        d = self._commit_dir(epoch)
+    def load_state(self, res: int, win: int) -> TileState | None:
+        d = self._commit_dir()
         if d is None:
             return None
         path = os.path.join(d, f"state-{res}-{win}.npz")
@@ -91,12 +77,12 @@ class CheckpointManager:
                     f"is idempotent) or replay with the writing version")
             return TileState(**{k: z[k] for k in TileState._fields})
 
-    def load_extra(self, name: str, epoch: int | None = None) -> dict | None:
+    def load_extra(self, name: str) -> dict | None:
         """A named extras payload committed alongside the window state
         (``extra-<name>.npz``), or None when the commit predates it —
         e.g. the inference engine's entity table (infer.engine).  Extras
         are auxiliary: absence never blocks a resume."""
-        d = self._commit_dir(epoch)
+        d = self._commit_dir()
         if d is None:
             return None
         path = os.path.join(d, f"extra-{name}.npz")
@@ -125,11 +111,10 @@ class CheckpointManager:
         mid-stream (ADVICE r4 #1).
 
         ``mesh_mode``: how the shard blocks were KEYED on a mesh run —
-        "shuffle" (mix32 key hash, parallel.sharded.ShardedAggregator)
-        vs "partitioned" (H3 parent cell, PartitionedAggregator).  Same
-        shape, different key ownership: restoring one into the other
-        would silently duplicate groups across devices, so the resume
-        refuses a mismatch (stream.runtime._maybe_resume).
+        "partitioned" (H3 parent cell, parallel.PartitionedAggregator).
+        Older checkpoints may say "shuffle" (a key hash, from a mesh
+        path since removed): same shape, different key ownership, so
+        the resume refuses them (stream.runtime._maybe_resume).
 
         ``extras``: named auxiliary payloads ({name: {key: array}}) —
         reducer state riding the same atomic commit as the window state

@@ -55,7 +55,6 @@ GAUGE_NAMES = frozenset({
 #                 mode, where foreign rows are the EXPECTED majority of
 #                 every poll — labeled apart so partition-skew drops
 #                 don't read as misrouted-topic trouble
-#   exchange      all_to_all lane-skew overflow (parallel.sharded)
 #   handoff       cross-shard entity handoff re-seeds (infer.engine):
 #                 the event itself WAS folded by the count path — the
 #                 tag records the Kalman reducer discarding an entity's
@@ -65,13 +64,12 @@ GAUGE_NAMES = frozenset({
 # ``Metrics.drop`` validates against this set (tests pin it closed) and
 # keeps the legacy flat counters in lockstep.
 DROP_REASONS = ("invalid", "late", "out_of_shard", "oversample",
-                "exchange", "handoff")
+                "handoff")
 _DROP_LEGACY = {
     "invalid": "events_invalid",
     "late": "events_late",
     "out_of_shard": "events_out_of_shard",
     "oversample": "events_out_of_shard",
-    "exchange": "events_bucket_dropped",
     "handoff": "infer_handoff_reseed",
 }
 

@@ -100,28 +100,6 @@ class _FeedBatch(NamedTuple):
                           # single-device fold's)
 
 
-def _make_global_pair(mesh):
-    """Cross-host agreement channel: every host contributes a triple of
-    flags, everyone reads the global sums.  This is a collective — hosts
-    must call it at the same point of every step (stream lockstep)."""
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from heatmap_tpu.parallel.multihost import put_global
-    from heatmap_tpu.parallel.sharded import AXIS
-
-    sharding = NamedSharding(mesh, P(AXIS, None))
-    n_local = sum(1 for d in mesh.devices.ravel()
-                  if d.process_index == jax.process_index())
-    f = jax.jit(lambda x: jnp.sum(x, axis=0))
-
-    def gpair(a: float, b: float, c: float = 0.0) -> np.ndarray:
-        local = np.tile(np.array([[a, b, c]], np.float32), (n_local, 1))
-        return np.asarray(jax.device_get(f(put_global(sharding, local))))
-
-    return gpair
-
-
 class MicroBatchRuntime:
     def __init__(
         self,
@@ -133,6 +111,11 @@ class MicroBatchRuntime:
         checkpoint_every: int = 20,
         view=None,
     ):
+        if jax.process_count() > 1:
+            raise RuntimeError(
+                "MicroBatchRuntime runs in one JAX process: to serve a pod, "
+                "run one runtime per host, split by HEATMAP_SHARDS/"
+                "HEATMAP_SHARD_INDEX, each over its own chips")
         self.cfg = cfg
         self.source = source
         self.store = store
@@ -161,21 +144,16 @@ class MicroBatchRuntime:
         # Materialized tile view (query.matview): fed by the writer
         # thread after each durable tile write, read by the serve layer
         # (delta/ETag/SSE/topk/?res=) so polls stop touching the Store.
-        # Multi-host and sharded runs skip the self-owned view — each
-        # process sinks only its own cell space, so a process-local view
-        # would expose a partial city; serve processes rebuild the
-        # merged city from the shared store instead, or a caller passes
-        # ``view=`` to fan several shards into one shared view.
+        # Sharded runs skip the self-owned view — each process sinks
+        # only its own cell space, so a process-local view would expose
+        # a partial city; serve processes rebuild the merged city from
+        # the shared store instead, or a caller passes ``view=`` to fan
+        # several shards into one shared view.
         # Integrity observatory (obs/audit.py, HEATMAP_AUDIT=1):
         # observe-only event-conservation ledger + per-window content
-        # digests.  Multi-host runs are not audited — their accounting
-        # is replicated across hosts and a host-local ledger could
-        # never telescope.  (AuditState itself is constructed below,
-        # after the fleet tag it is named by.)
-        self._audit_on = bool(cfg.audit) and jax.process_count() == 1
-        if cfg.audit and not self._audit_on:
-            log.warning("HEATMAP_AUDIT=1 ignored: multi-host runs are "
-                        "not audited (replicated lockstep accounting)")
+        # digests.  (AuditState itself is constructed below, after the
+        # fleet tag it is named by.)
+        self._audit_on = bool(cfg.audit)
         self.matview = None
         if view is not None:
             # externally shared view (sharded fan-in): every shard's
@@ -183,8 +161,7 @@ class MicroBatchRuntime:
             # cell spaces are disjoint by the shardmap, so the merge is
             # upsert-only with no cross-shard conflicts by construction
             self.matview = view
-        elif (cfg.query_view and jax.process_count() == 1
-                and self.shardmap is None):
+        elif cfg.query_view and self.shardmap is None:
             from heatmap_tpu.query import TileMatView
 
             view_audit = None
@@ -263,15 +240,8 @@ class MicroBatchRuntime:
         self._member_pub_last = 0.0  # fleet member-snapshot rate limit
         from heatmap_tpu.obs.xproc import ENV_FLEET_TAG
 
-        # a HEATMAP_FLEET_TAG override reaches every shard of a
-        # multi-process runtime through the shared env — compose it
-        # with the process index so shards can never collide on one
-        # member file (a dead shard hiding behind a live one's
-        # snapshot) or one lineage-id namespace
         tag = os.environ.get(ENV_FLEET_TAG)
         idx = jax.process_index()
-        if tag and jax.process_count() > 1:
-            tag = f"{tag}-p{idx}"
         # shard runtimes default to a shard<i> tag so fleet surfaces
         # (/fleet/metrics, /fleet/healthz, the per-shard watermark
         # files) name the shard, not a generic process index — and two
@@ -437,8 +407,7 @@ class MicroBatchRuntime:
         # per-shard checkpoint namespace: N shard children share one
         # CHECKPOINT env, but each owns its own offsets/state — a
         # restarted shard resumes and replays ONLY its own stream
-        # position (the multi-host p<idx> subdirectory discipline,
-        # applied to the shard axis)
+        # position
         ckpt_dir = (f"{cfg.checkpoint_dir}/shard{cfg.shard_index}"
                     if self.shardmap is not None else cfg.checkpoint_dir)
         self.ckpt = CheckpointManager(ckpt_dir)
@@ -459,9 +428,7 @@ class MicroBatchRuntime:
         # one pull's round trips.  Flush is forced before
         # every checkpoint capture, on idle polls, at close, and under
         # watermark/growth pressure, so sink semantics and
-        # replay-equivalence are unchanged.  Multi-host forces K=1:
-        # accounting feeds the replicated grow/overflow decisions, which
-        # must advance in lockstep.
+        # replay-equivalence are unchanged.
         from heatmap_tpu.engine.step import EmitRing
 
         self._ring = EmitRing(cfg.emit_flush_k)
@@ -480,8 +447,6 @@ class MicroBatchRuntime:
         # tracker wraps the jitted entry points below; the memory
         # monitor samples on the step loop (1 Hz) and keeps the HBM /
         # live-buffer watermarks /healthz budgets compare against.
-        # The ring-bytes callback reads self._ring dynamically — the
-        # multi-host branch may swap the ring for a depth-1 one.
         from heatmap_tpu.obs.runtimeinfo import RuntimeIntrospection
 
         self.runtimeinfo = RuntimeIntrospection(
@@ -557,122 +522,77 @@ class MicroBatchRuntime:
             cap = grown
         bins = cfg.speed_hist_bins
         self._multi = None
-        self._sharded = None
         self._parted = None
-        self._mesh_mode = None          # "partitioned" | "shuffle" | None
-        self.meshmap = None             # MeshPartition (partitioned mode)
+        self._mesh_mode = None          # "partitioned" | None
+        self.meshmap = None             # MeshPartition (mesh runs)
         self._mesh_rings = None         # per-device EmitRings
         self._mesh_governors = None     # per-device BatchGovernors
-        # knob-pin telemetry (satellite bugfix): any place that silently
-        # degrades the fast path (multi-host forcing emit_flush_k=1 /
-        # prefetch=0, a governor request a topology can't honor) records
-        # its reason here AND pins heatmap_fastpath_pinned{reason=}=1,
-        # so an attached run that lost the ring is diagnosable from
-        # /metrics and /healthz instead of one INFO log line
-        self._fastpath_pinned: dict[str, str] = {}
-        self._g_fastpath_pinned = self.metrics.gauge(
-            "heatmap_fastpath_pinned",
-            "1 per reason the runtime pinned fast-path knobs down "
-            "(emit_flush_k=1 / prefetch_batches=0 for multi-host "
-            "lockstep, a governor request the topology cannot honor) — "
-            "a run silently serving degraded throughput is diagnosable "
-            "from telemetry",
-            labels=("reason",))
         if mesh is not None and mesh.devices.size > 1:
-            mesh_multiproc = jax.process_count() > 1
-            want_part = (cfg.mesh_partitioned in ("auto", "1")
-                         and not mesh_multiproc)
-            if cfg.mesh_partitioned == "1" and mesh_multiproc:
-                log.warning(
-                    "HEATMAP_MESH_PARTITIONED=1 ignored: multi-host "
-                    "meshes keep the ICI-shuffle lockstep path")
-            if want_part:
-                # partitioned fast path (ISSUE 11 tentpole): the feed
-                # pre-partitions each batch by H3 parent cell and every
-                # device runs the fused single-device program over ITS
-                # OWN rows — no all_to_all, no lockstep, per-device
-                # emit rings + governors (parallel.sharded
-                # .PartitionedAggregator); merged at the view
-                # upsert-only exactly like the PR 7 process fleet.
-                from heatmap_tpu.engine.step import EmitRing as _Ring
-                from heatmap_tpu.parallel import PartitionedAggregator
-                from heatmap_tpu.stream.shardmap import MeshPartition
+            # the partitioned mesh: the feed pre-partitions each batch by
+            # H3 parent cell and every device runs the fused
+            # single-device program over ITS OWN rows — no collectives,
+            # per-device emit rings + governors
+            # (parallel.PartitionedAggregator); merged at the view
+            # upsert-only exactly like the PR 7 process fleet.
+            from heatmap_tpu.engine.step import EmitRing as _Ring
+            from heatmap_tpu.parallel import PartitionedAggregator
+            from heatmap_tpu.stream.shardmap import MeshPartition
 
-                self._parted = PartitionedAggregator(
-                    mesh,
-                    [AggParams(res=res, window_s=win_s,
-                               emit_capacity=min(cfg.batch_size, cap),
-                               speed_hist_max=cfg.speed_hist_max_kmh)
-                     for res, win_s in pairs],
-                    capacity_per_shard=cap, batch_size=cfg.batch_size,
-                    hist_bins=bins,
-                )
-                self._parted.instrument(self.runtimeinfo.compile.wrap)
-                self._mesh_mode = "partitioned"
-                self.meshmap = MeshPartition(
-                    self._parted.n_shards, min(cfg.resolutions),
-                    cfg.shard_res, outer_shards=cfg.shards)
-                log.info("partitioned mesh runtime: %s",
-                         self.meshmap.describe())
-                for res, win_s in pairs:
-                    self.aggs[(res, win_s // 60)] = \
-                        self._parted.view(res, win_s)
-                n_dev = self._parted.n_shards
-                self._mesh_rings = [_Ring(cfg.emit_flush_k)
-                                    for _ in range(n_dev)]
-                self._mesh_epoch_pend: dict[int, int] = {}
-                self._mesh_shard_active: dict[tuple, int] = {}
-                self._shard_active_peak = 0
-                self._mesh_idle: dict[tuple, tuple] = {}
-                self._mesh_rows = [0] * n_dev
-                self._mesh_pulls = [0] * n_dev
-                self._mesh_pull_batches = [0] * n_dev
-                self.metrics.gauge(
-                    "heatmap_mesh_devices",
-                    "mesh devices running the partitioned shard-per-"
-                    "device fast path (0/absent = not a partitioned "
-                    "mesh run)").set(n_dev)
-                self._c_mesh_rows = self.metrics.registry.counter(
-                    "heatmap_mesh_rows_total",
-                    "live rows folded per mesh shard (the feed's H3 "
-                    "partition of each batch)", labels=("shard",))
-                self._c_mesh_pulls = self.metrics.registry.counter(
-                    "heatmap_mesh_pulls_total",
-                    "device->host emit pulls per mesh shard (one per "
-                    "ring flush; the idle-flush floor on cold shards)",
-                    labels=("shard",))
-                ring_fam = self.metrics.gauge(
-                    "heatmap_mesh_ring_pending",
-                    "packed emit batches parked on each mesh shard's "
-                    "device awaiting its next flush",
-                    labels=("shard",))
-                for d in range(n_dev):
-                    ring_fam.labels(shard=str(d)).fn = (
-                        lambda i=d: len(self._mesh_rings[i]))
-                    # materialize the per-shard counter children so the
-                    # exposition carries every shard from step one
-                    self._c_mesh_rows.labels(shard=str(d))
-                    self._c_mesh_pulls.labels(shard=str(d))
-            else:
-                from heatmap_tpu.parallel import ShardedAggregator
-
-                # ALL pairs fused into one sharded program: one
-                # dispatch, one all_to_all, one addressable pull per
-                # batch (parallel.sharded)
-                self._sharded = ShardedAggregator(
-                    mesh,
-                    [AggParams(res=res, window_s=win_s,
-                               emit_capacity=min(cfg.batch_size, cap),
-                               speed_hist_max=cfg.speed_hist_max_kmh)
-                     for res, win_s in pairs],
-                    capacity_per_shard=cap, batch_size=cfg.batch_size,
-                    hist_bins=bins, bucket_factor=cfg.bucket_factor,
-                )
-                self._sharded.instrument(self.runtimeinfo.compile.wrap)
-                self._mesh_mode = "shuffle"
-                for res, win_s in pairs:
-                    self.aggs[(res, win_s // 60)] = \
-                        self._sharded.view(res, win_s)
+            self._parted = PartitionedAggregator(
+                mesh,
+                [AggParams(res=res, window_s=win_s,
+                           emit_capacity=min(cfg.batch_size, cap),
+                           speed_hist_max=cfg.speed_hist_max_kmh)
+                 for res, win_s in pairs],
+                capacity_per_shard=cap, batch_size=cfg.batch_size,
+                hist_bins=bins,
+            )
+            self._parted.instrument(self.runtimeinfo.compile.wrap)
+            self._mesh_mode = "partitioned"
+            self.meshmap = MeshPartition(
+                self._parted.n_shards, min(cfg.resolutions),
+                cfg.shard_res, outer_shards=cfg.shards)
+            log.info("partitioned mesh runtime: %s",
+                     self.meshmap.describe())
+            for res, win_s in pairs:
+                self.aggs[(res, win_s // 60)] = \
+                    self._parted.view(res, win_s)
+            n_dev = self._parted.n_shards
+            self._mesh_rings = [_Ring(cfg.emit_flush_k)
+                                for _ in range(n_dev)]
+            self._mesh_epoch_pend: dict[int, int] = {}
+            self._mesh_shard_active: dict[tuple, int] = {}
+            self._shard_active_peak = 0
+            self._mesh_idle: dict[tuple, tuple] = {}
+            self._mesh_rows = [0] * n_dev
+            self._mesh_pulls = [0] * n_dev
+            self._mesh_pull_batches = [0] * n_dev
+            self.metrics.gauge(
+                "heatmap_mesh_devices",
+                "mesh devices running the partitioned shard-per-"
+                "device fast path (0/absent = not a partitioned "
+                "mesh run)").set(n_dev)
+            self._c_mesh_rows = self.metrics.registry.counter(
+                "heatmap_mesh_rows_total",
+                "live rows folded per mesh shard (the feed's H3 "
+                "partition of each batch)", labels=("shard",))
+            self._c_mesh_pulls = self.metrics.registry.counter(
+                "heatmap_mesh_pulls_total",
+                "device->host emit pulls per mesh shard (one per "
+                "ring flush; the idle-flush floor on cold shards)",
+                labels=("shard",))
+            ring_fam = self.metrics.gauge(
+                "heatmap_mesh_ring_pending",
+                "packed emit batches parked on each mesh shard's "
+                "device awaiting its next flush",
+                labels=("shard",))
+            for d in range(n_dev):
+                ring_fam.labels(shard=str(d)).fn = (
+                    lambda i=d: len(self._mesh_rings[i]))
+                # materialize the per-shard counter children so the
+                # exposition carries every shard from step one
+                self._c_mesh_rows.labels(shard=str(d))
+                self._c_mesh_pulls.labels(shard=str(d))
         else:
             # single device: ALL pairs fused into one program — one
             # dispatch and one device->host pull per batch regardless of
@@ -690,9 +610,7 @@ class MicroBatchRuntime:
         self._g_capacity.set(cap)
         # HEATMAP_H3_IMPL=native: snap on the host (C++, ~11x faster per
         # CPU core than the XLA-CPU snap and f64-exact) and feed the fold
-        # pre-computed keys — both paths: the fused single-device step
-        # (engine.multi prekeys) and the sharded step (each host snaps
-        # its LOCAL slice; parallel.sharded prekeys).
+        # pre-computed keys (engine.multi prekeys).
         self._host_snap = None
         self._idle_keys: dict[int, dict] = {}  # zero snap keys by shape
         h3_impl = os.environ.get("HEATMAP_H3_IMPL", "auto")
@@ -720,9 +638,8 @@ class MicroBatchRuntime:
         engine_step.inprogram_snap_name(min(cfg.resolutions))
         # Freeze the merge-impl bank verdict the same way (r5 review):
         # one snapshot at init — never the live file from inside a
-        # trace — so a bank rewritten mid-run cannot
-        # recompile a different lockstep program after the multihost
-        # collective below validated this snapshot.
+        # trace — so a bank rewritten mid-run cannot recompile a
+        # different program at a slab-growth retrace.
         from heatmap_tpu import hwbank
 
         merge_pin = hwbank.merge_winner()
@@ -774,103 +691,7 @@ class MicroBatchRuntime:
                     window_minutes_tag=0 if default else wmin,
                     with_p95=bins > 0,
                 )
-        # multi-host: each process feeds its share of the global batch and
-        # checkpoints its own shards under a per-process subdirectory
-        # (per-host Kafka partitions → per-host offsets; parallel.multihost)
         self._feed_batch = cfg.batch_size
-        self._multiproc = jax.process_count() > 1
-        if self._multiproc and (self._ring.capacity > 1
-                                or self._prefetch_n):
-            # lockstep runs: accounting (the replicated grow/overflow
-            # inputs) and poll ordering must advance identically on every
-            # host, so emit accumulation and prefetch stay single-host
-            # optimizations for now (EmitRing imported above)
-            log.info("multi-host run: forcing emit_flush_k=1 and "
-                     "prefetch_batches=0 (lockstep accounting)")
-            self._note_fastpath_pinned(
-                "multihost_lockstep",
-                f"emit_flush_k {self._ring.capacity}->1, "
-                f"prefetch_batches {self._prefetch_n}->0")
-            self._ring = EmitRing(1)
-            self._prefetch_n = 0
-        if self._multiproc:
-            from heatmap_tpu.parallel.multihost import global_batch_to_local
-
-            if mesh is None or len(
-                    {d.process_index for d in mesh.devices.ravel()}) < 2:
-                # independent per-host SingleAggregators would upsert
-                # partial counts for the SAME tile _ids (silent clobbering)
-                raise ValueError(
-                    "multi-process run requires a global sharded mesh "
-                    "spanning all processes (parallel.make_mesh after "
-                    "multihost.init_from_env)")
-            self._feed_batch = global_batch_to_local(cfg.batch_size)
-            self.ckpt = CheckpointManager(
-                f"{cfg.checkpoint_dir}/p{jax.process_index()}")
-            self._gpair = _make_global_pair(mesh)
-            self._global_live = 1.0
-            # cross-host agreement on the native host snap: hosts with
-            # and without the C++ toolchain would dispatch DIFFERENT
-            # jitted programs (_step_packed_pre vs _step_packed) into the
-            # same lockstep collectives — and even benignly, f64-exact
-            # C++ keys on one host vs f32 XLA keys on another would make
-            # tile membership depend on which host ingested the event.
-            # One startup collective (run unconditionally: an env var
-            # skewed across hosts must not desync the barrier itself)
-            # keeps the choice all-or-nothing.
-            have, total, _ = self._gpair(
-                1.0 if self._host_snap is not None else 0.0, 1.0)
-            if self._host_snap is not None and have != total:
-                log.warning(
-                    "HEATMAP_H3_IMPL=native disabled: only %d/%d shards "
-                    "have the C++ toolchain — a split would desync the "
-                    "lockstep programs", int(have), int(total))
-                self._host_snap = None
-            elif self._host_snap is None and have > 0:
-                log.warning(
-                    "peer hosts requested the native snap but this host "
-                    "can't provide it; all hosts fall back to in-program")
-            self._require_partition_snap()
-            # cross-host agreement on the BANK-derived trace-time
-            # choices (r5 review): each host resolved its snap policy
-            # and merge winner from its LOCAL bank (hwbank) above; a
-            # skewed checkout/bank must not let hosts trace different
-            # kernels (pallas-vs-xla snaps re-key f32 cell-edge events
-            # by ingesting host; divergent merge impls compile
-            # different lockstep programs).  Unanimity per value via
-            # zero-variance over (code, code^2) sums — every host
-            # reaches the same verdict, so the fallbacks converge.
-            from heatmap_tpu.engine import step as engine_step
-
-            def _unanimous(code: float) -> bool:
-                s, s2, n = self._gpair(code, code * code, 1.0)
-                return bool(s == code * n and s2 == code * code * n)
-
-            # the RESOLVED kernel must match on every host.  A host that
-            # cannot lower a Pallas policy raised above; what remains is
-            # policy skew.  A bank-derived "pallas" yields to the XLA
-            # snap; an explicit HEATMAP_H3_IMPL=pallas does not.
-            snap_resolved = engine_step.inprogram_snap_name(
-                min(cfg.resolutions))
-            if not _unanimous(1.0 if snap_resolved == "pallas" else 0.0):
-                if snap_resolved == "pallas" and h3_impl == "pallas":
-                    raise RuntimeError(
-                        "HEATMAP_H3_IMPL=pallas but not every host "
-                        "resolves the Pallas snap")
-                if snap_resolved == "pallas":
-                    log.warning(
-                        "banked pallas snap disabled: not every host "
-                        "resolves it — all hosts use the XLA snap")
-                engine_step.SNAP_IMPL = "xla"
-            mw = engine_step.MERGE_BANK_PIN  # frozen snapshot from above
-            if not _unanimous(
-                    float({"sort": 1, "rank": 2, "probe": 3}.get(mw, 0))):
-                if mw is not None:
-                    log.warning(
-                        "banked merge winner %r ignored: hosts' "
-                        "hardware banks disagree — every host uses the "
-                        "static auto rule", mw)
-                engine_step.MERGE_BANK_PIN = None
 
         # the pair whose stats define the batch-level counters
         self._primary = (
@@ -884,10 +705,8 @@ class MicroBatchRuntime:
         # HEATMAP_GOVERN=1 the static batch/flush-K/prefetch knobs
         # become INITIAL values and a feedback governor on the step
         # loop resizes them against HEATMAP_SLO_FRESHNESS_P50_MS.
-        # Single-device fused path only: multi-host lockstep pins the
-        # knobs (accounting must advance identically on every host),
-        # and the mesh-sharded program's collective shapes are not on
-        # the warmed ladder.  Each H3-partitioned shard process
+        # One governor on the single-device fused path, one per device
+        # on the partitioned mesh.  Each H3-partitioned shard process
         # (stream/shardmap.py) governs independently — skewed shards
         # converge to different batch sizes while the watermark-aligned
         # cutoff stays fleet-bounded; the fleet member snapshot carries
@@ -896,18 +715,7 @@ class MicroBatchRuntime:
         # its final shape.
         self.governor = None
         if cfg.govern:
-            if self._multiproc or (self._multi is None
-                                   and self._parted is None):
-                log.warning(
-                    "HEATMAP_GOVERN=1 ignored: the governor runs the "
-                    "fused single-device path and the partitioned mesh "
-                    "path only (multi-host and ICI-shuffle runs pin "
-                    "their knobs for lockstep)")
-                self._note_fastpath_pinned(
-                    "govern_unsupported_topology",
-                    "HEATMAP_GOVERN=1 ignored (multi-host or "
-                    "ICI-shuffle mesh: knobs pinned for lockstep)")
-            elif self._parted is not None:
+            if self._parted is not None:
                 # per-mesh-shard governing (ISSUE 11 tentpole (3)): one
                 # AIMD governor per device over a SHARED warmed ladder —
                 # skewed devices converge to different batch buckets
@@ -1029,34 +837,7 @@ class MicroBatchRuntime:
 
     # ------------------------------------------------------------------
     def _maybe_resume(self) -> None:
-        at_epoch: int | None = None
-        if self._multiproc:
-            # hosts may have crashed between each other's commits; agree on
-            # the newest epoch EVERY host retains, or start fresh together.
-            # KEEP_COMMITS=2 covers the <=1-commit divergence the commit
-            # barrier allows.
-            from jax.experimental import multihost_utils
-
-            local = self.ckpt.available_epochs()
-            latest = local[-1] if local else -1
-            common = int(np.min(multihost_utils.process_allgather(
-                np.int64(latest))))
-            if common < 0:
-                if latest >= 0:
-                    log.warning(
-                        "peer host has no checkpoint; discarding local "
-                        "commits (epochs %s) and starting fresh", local)
-                return
-            if common not in local:
-                raise RuntimeError(
-                    f"hosts diverged beyond checkpoint retention: common "
-                    f"epoch {common} not in local commits {local}; clear "
-                    f"{self.cfg.checkpoint_dir} on every host")
-            if common != latest:
-                log.warning("resuming at common epoch %d (local latest %d)",
-                            common, latest)
-            at_epoch = common
-        meta = self.ckpt.load_meta(epoch=at_epoch)
+        meta = self.ckpt.load_meta()
         if not meta:
             return
         log.info("resuming from checkpoint: %s", meta)
@@ -1073,22 +854,22 @@ class MicroBatchRuntime:
         ck_mode = meta.get("mesh_mode")
         if ck_mode is None and snap_shards is not None and snap_shards > 1:
             # pre-mesh-mode multi-shard checkpoints all came from the
-            # ICI-shuffle path (the only mesh mode that existed)
+            # removed shuffle mesh (the only mesh mode that existed)
             ck_mode = "shuffle"
         if (ck_mode or self._mesh_mode) and ck_mode != self._mesh_mode:
-            # same block layout, DIFFERENT key ownership (mix32 hash vs
-            # H3 parent): a cross-mode restore would silently duplicate
-            # groups across devices
+            # the removed "shuffle" mesh keyed its shard blocks by a key
+            # hash, not by H3 parent cell: restored here, its groups
+            # would land on devices that do not own them
             raise RuntimeError(
                 f"checkpoint state was keyed in mesh mode {ck_mode!r} "
-                f"but this run is {self._mesh_mode!r}; restore the "
-                f"original mode (HEATMAP_MESH_PARTITIONED) or clear "
-                f"{self.cfg.checkpoint_dir}")
+                f"but this run is {self._mesh_mode!r}; this runtime "
+                f"cannot restore it: clear {self.cfg.checkpoint_dir} "
+                f"and replay the stream")
         self.epoch = meta.get("epoch", 0)
         self.max_event_ts = meta.get("max_event_ts", I32_MIN)
         self.source.seek(meta.get("offset"))
         for (res, wmin), agg in self.aggs.items():
-            st = self.ckpt.load_state(res, wmin * 60, epoch=at_epoch)
+            st = self.ckpt.load_state(res, wmin * 60)
             if st is None:
                 continue
             st = TileState(*st)
@@ -1116,7 +897,7 @@ class MicroBatchRuntime:
             # extras are auxiliary: a commit predating the reducer (or
             # written with kalman off) yields None and the engine simply
             # starts cold — filters re-seed from the replayed stream
-            data = self.ckpt.load_extra("infer", epoch=at_epoch)
+            data = self.ckpt.load_extra("infer")
             if data is not None:
                 self.infer.restore(data, self._intern_v)
                 log.info("restored inference entity table: %d entities",
@@ -1125,7 +906,7 @@ class MicroBatchRuntime:
             # pending scorecards survive the restart and score against
             # the HISTORY tier when their target spans have already
             # left the rebuilt live view
-            data = self.ckpt.load_extra("quality", epoch=at_epoch)
+            data = self.ckpt.load_extra("quality")
             if data is not None:
                 n = self.quality.restore_extra(data)
                 log.info("restored quality ledger: %d pending "
@@ -1153,16 +934,14 @@ class MicroBatchRuntime:
         """A partitioned stream (process shards, the partitioned mesh)
         routes rows by their host-snapped cell, so its fold must group
         by that same cell: raise whenever the host snap is off, at
-        start-up, after the multihost agreement and after a checkpoint
-        pin alike."""
+        start-up and after a checkpoint pin alike."""
         if self._partitioned and self._host_snap is None:
             raise RuntimeError(
                 "a partitioned stream (HEATMAP_SHARDS > 1 or a partitioned "
                 "mesh) groups by its host-snapped partition cells: it "
                 "needs the C++ toolchain, resolutions <= 10, "
                 "HEATMAP_H3_IMPL=auto|native and no checkpoint keyed with "
-                "an in-program snap (HEATMAP_MESH_PARTITIONED=0 keeps a "
-                "mesh on the shuffle path)")
+                "an in-program snap")
 
     def _pin_snap_impl(self, ck_snap: str | None) -> None:
         """Keep the snap impl FIXED across a resume (ADVICE r4 #1).
@@ -1176,9 +955,6 @@ class MicroBatchRuntime:
         env override is honored but the re-keying hazard is logged.
         """
         if ck_snap not in ("native", "xla", "pallas"):
-            # host-uniform branch: the field is written post-agreement,
-            # so every host sees the same (absent/legacy) value and none
-            # reaches the collective below — no desync
             return
         if ck_snap != self._snap_impl_name:
             if self._h3_env != "auto":
@@ -1218,53 +994,23 @@ class MicroBatchRuntime:
                 log.info("pinned H3 snap impl %r from checkpoint "
                          "(was %r under HEATMAP_H3_IMPL=auto)",
                          self._snap_impl_name, was)
-        if self._multiproc:
-            # same all-or-nothing rule as startup.  EVERY host must reach
-            # this collective whenever ck_snap is valid — the pin outcome
-            # is per-host (toolchain loss, skewed HEATMAP_H3_IMPL), so an
-            # early return above on one host would strand its peers in
-            # the barrier (r5 review finding)
-            have, total, _ = self._gpair(
-                1.0 if self._host_snap is not None else 0.0, 1.0)
-            if self._host_snap is not None and have != total:
-                log.warning(
-                    "only %d/%d hosts resolved the native snap after the "
-                    "checkpoint pin; all hosts fall back to in-program "
-                    "(f32 cell-edge events may re-key)", int(have),
-                    int(total))
-                self._host_snap = None
-            elif self._host_snap is None and have > 0:
-                log.warning(
-                    "peer hosts resolved the native snap but this host "
-                    "cannot; all hosts fall back to in-program")
         self._require_partition_snap()
 
     def _agg(self):
         """Whichever aggregator this runtime drives: the fused
-        single-device program, the ICI-shuffle mesh, or the partitioned
-        shard-per-device mesh."""
+        single-device program or the partitioned shard-per-device
+        mesh."""
         if self._multi is not None:
             return self._multi
-        if self._sharded is not None:
-            return self._sharded
         return self._parted
 
     @property
     def _local_shards(self) -> int:
-        """Shard blocks in THIS process's snapshots (1 on the fused
+        """Shard blocks in this runtime's snapshots (1 on the fused
         single-device path)."""
-        if self._sharded is not None:
-            return self._sharded.local_shards
         if self._parted is not None:
-            return self._parted.local_shards
+            return self._parted.n_shards
         return 1
-
-    def _note_fastpath_pinned(self, reason: str, detail: str) -> None:
-        """Record a fast-path knob pin (satellite bugfix): gauge child
-        per reason + the dict /healthz surfaces, so a run silently
-        serving degraded throughput is diagnosable from telemetry."""
-        self._fastpath_pinned[reason] = detail
-        self._g_fastpath_pinned.labels(reason=reason).set(1.0)
 
     def _ring_pending(self) -> int:
         """Parked emit batches bounding the stats lag: the single ring's
@@ -1297,24 +1043,7 @@ class MicroBatchRuntime:
             agg.restore(resize_state(st, agg.capacity_per_shard, shards))
 
     def _checkpoint(self) -> None:
-        if self._multiproc:
-            # The mid-carry skip must be decided COLLECTIVELY.  close()
-            # reaches this point on every host (lockstep exits: the
-            # max_batches counter advances on the global had-events flag,
-            # and _fatal derives from replicated stats), but the carry is
-            # per-host — run(max_batches=N) can end with one host
-            # mid-carry while its peers are carry-free.  A local early
-            # return here would strand those peers in the commit barrier
-            # below forever.  All hosts agree first: if ANY carries, ALL
-            # skip (the uncommitted tail just replays on resume — every
-            # sink write is an idempotent upsert).  The step-loop call
-            # site gates on the same global flag, so this collective is
-            # reached on all hosts there too (it reads carry_any == 0).
-            _, _, carry_any = self._gpair(
-                0.0, 0.0, float(self._carried_last))
-            if carry_any > 0:
-                return
-        elif self._carried_last:
+        if self._carried_last:
             # mid-record: the last DISPATCHED batch overshot and its
             # record's tail rows are still undispatched (in _carry_cols
             # or the prefetch queue) — state would double-fold the
@@ -1328,38 +1057,15 @@ class MicroBatchRuntime:
             self._checkpoint_capture()
 
     def _checkpoint_capture(self) -> None:
-        """The flush barrier, then (multi-host) the synchronous commit,
-        or (one host) the join of the previous commit and the dispatch
-        of fresh device copies of every pair's state, handed to the
-        ``ckpt-commit`` thread."""
+        """The flush barrier, then the join of the previous commit and
+        the dispatch of fresh device copies of every pair's state,
+        handed to the ``ckpt-commit`` thread."""
         # the commit must cover every batch whose offsets it advances past
         self.flush_pending()
-        if self._multiproc:
-            # all hosts reach the commit point (same epoch — epochs advance
-            # in lockstep) before any commits, so retained commits can
-            # never diverge by more than one epoch across hosts.  Stays
-            # synchronous: collectives must not run off the step thread.
-            from jax.experimental import multihost_utils
-
-            multihost_utils.sync_global_devices(f"heatmap-ckpt-{self.epoch}")
-            # commit AFTER sink writes are durable (idempotent replay window)
-            self.writer.drain()
-            states = {
-                (res, wmin * 60): agg.snapshot()
-                for (res, wmin), agg in self.aggs.items()
-            }
-            self.metrics.count("checkpoint_bytes", _state_bytes(states))
-            self.ckpt.commit(self._offsets_dispatched, self.max_event_ts,
-                             self.epoch, states, shards=self._local_shards,
-                             snap_impl=self._snap_impl_name,
-                             mesh_mode=self._mesh_mode,
-                             extras=self._infer_extras())
-            self.metrics.count("checkpoints")
-            return
-        # Single host: capture fresh-buffer device copies + offsets now
-        # (device copies dispatch asynchronously), then drain/transfer/
-        # write on a background thread so checkpoint batches don't stall
-        # the step loop.
+        # Capture fresh-buffer device copies + offsets now (device copies
+        # dispatch asynchronously), then drain/transfer/write on a
+        # background thread so checkpoint batches don't stall the step
+        # loop.
         self._ckpt_join()  # serialize with the previous in-flight commit
         snaps = {
             (res, wmin * 60): (agg.device_snapshot(), agg.to_host)
@@ -1606,59 +1312,31 @@ class MicroBatchRuntime:
         span): one pull of every parked batch, then its accounting."""
         n_batches = len(self._ring)
         batch_max = I32_MIN
-        if self._multi is not None:
-            from heatmap_tpu.engine.multi import stats_from_packed
+        from heatmap_tpu.engine.multi import stats_from_packed
 
-            # emit_pull=prefix (the off-CPU auto choice): head rows +
-            # one shared live-prefix bucket instead of the full (K*P,
-            # E+1, L) stack — KB instead of MB per flush on remote-
-            # attached chips (engine.step.pull_packed_stack)
-            flushed = self._ring.flush_stacked(self._prefix_pull)
-            residency = self._ring.last_flush_residency
-            for i, (bufs, epoch) in enumerate(flushed):
-                bm = I32_MIN
-                for idx, (res, win_s) in enumerate(self._multi.pairs):
-                    stats = stats_from_packed(bufs[idx])
-                    bm = max(
-                        bm,
-                        self._account_pair_packed(res, win_s // 60,
-                                                  bufs[idx][1:], stats,
-                                                  epoch),
-                    )
-                batch_max = self._book_flushed_batch(bm, batch_max)
-                self._note_flushed(
-                    epoch, residency[i] if i < len(residency) else None)
-        else:
-            from heatmap_tpu.parallel import multihost
-            from heatmap_tpu.parallel.sharded import packed_pair_bodies
-
-            # sharded path: per-entry addressable pulls (stacking global
-            # sharded arrays eagerly would bounce through collectives);
-            # accumulation still lets the device run ahead K batches
-            entries = self._ring.take()
-            residency = self._ring.last_flush_residency
-            for i, (packed, epoch) in enumerate(entries):
-                rows = multihost.addressable_rows(packed)
-                bodies = packed_pair_bodies(
-                    rows, self._sharded.params.emit_capacity,
-                    len(self._sharded.pairs))
-                bm = I32_MIN
-                for (res, win_s), (body, stats) in zip(self._sharded.pairs,
-                                                       bodies):
-                    bm = max(
-                        bm,
-                        self._account_pair_packed(res, win_s // 60, body,
-                                                  stats, epoch),
-                    )
-                batch_max = self._book_flushed_batch(bm, batch_max)
-                self._note_flushed(
-                    epoch, residency[i] if i < len(residency) else None)
+        # emit_pull=prefix (the off-CPU auto choice): head rows +
+        # one shared live-prefix bucket instead of the full (K*P,
+        # E+1, L) stack — KB instead of MB per flush on remote-
+        # attached chips (engine.step.pull_packed_stack)
+        flushed = self._ring.flush_stacked(self._prefix_pull)
+        residency = self._ring.last_flush_residency
+        for i, (bufs, epoch) in enumerate(flushed):
+            bm = I32_MIN
+            for idx, (res, win_s) in enumerate(self._multi.pairs):
+                stats = stats_from_packed(bufs[idx])
+                bm = max(
+                    bm,
+                    self._account_pair_packed(res, win_s // 60,
+                                              bufs[idx][1:], stats,
+                                              epoch),
+                )
+            batch_max = self._book_flushed_batch(bm, batch_max)
+            self._note_flushed(
+                epoch, residency[i] if i < len(residency) else None)
         self._prune_vel_views()
         # pull accounting: the fused path crosses the link once per
-        # flush (the stacked transfer); the sharded path pays one
-        # addressable pull PER parked entry — count what was paid
-        self.metrics.count("emit_pulls",
-                           1 if self._multi is not None else n_batches)
+        # flush (the stacked transfer)
+        self.metrics.count("emit_pulls", 1)
         self.metrics.count("emit_pull_batches", n_batches)
         if batch_max > I32_MIN:
             # device truth catches any undercount of the host-side
@@ -1931,18 +1609,6 @@ class MicroBatchRuntime:
                     f"2^{self.cfg.state_capacity_log2} per shard; raise "
                     f"STATE_CAPACITY_LOG2, or set HEATMAP_ON_OVERFLOW=error "
                     f"to keep running with the loss surfaced at /metrics")
-        dropped = int(getattr(stats, "bucket_dropped", 0))
-        if dropped:
-            # ledger forwarding only for the primary pair: the event
-            # conservation identity counts each event once, and
-            # secondary pairs' exchange drops are a per-pair detail
-            self.metrics.drop("exchange", dropped,
-                              audit=(res, wmin) == self._primary)
-            log.error(
-                "EXCHANGE OVERFLOW: %d events dropped by all_to_all lane "
-                "skew for (res=%d, window=%dm); raise bucket_factor",
-                dropped, res, wmin,
-            )
         if (res, wmin) == self._primary:
             self.metrics.count("events_valid", int(stats.n_valid))
             # watermark-late (incl. the future-window poison drop the
@@ -1992,34 +1658,24 @@ class MicroBatchRuntime:
         A batch adds at most one new group per event per pair, so keeping
         free slots above 2x the global batch (the 2 covers the one-batch
         stats lag) makes single-slab overflow structurally impossible
-        below the growth ceiling.  Sharded slabs overflow per shard; the
-        extra 2x on the occupancy term tolerates up to 2x accumulated
-        key-ownership skew (far above what mix32 produces at real group
-        counts), with the overflow accounting as the loud backstop.
+        below the growth ceiling.
         Runs on the step thread between the flush and the next dispatch —
         the emit ring is drained first (the step loop pressure-flushes
         whenever growth may trigger), so no packed emit ever straddles an
         emit-capacity resize and the resize is a plain state swap plus a
-        retrace on the next step.  In multi-host mode every host derives
-        the same decision from the replicated stats.  On the partitioned
-        mesh per-shard occupancy is EXACT (each device holds only its
-        own cells), so the inequality runs against the hottest shard
-        with the full margin — one batch CAN mint its whole row count
-        into a single device under total geographic skew."""
+        retrace on the next step.  On the partitioned mesh per-shard
+        occupancy is EXACT (each device holds only its own cells), so
+        the inequality runs against the hottest shard with the full
+        margin — one batch CAN mint its whole row count into a single
+        device under total geographic skew."""
         agg = self._agg()
         margin = self._grow_margin()
         cap = agg.capacity_per_shard
-        if self._parted is not None:
-            peak, shards, skew = self._shard_active_peak, 1, 1
-        else:
-            shards = agg.n_shards
-            skew = 2 if shards > 1 else 1
-            peak = self._n_active_peak
-        if peak * skew + margin <= cap * shards:
+        peak = self._active_peak()
+        if peak + margin <= cap:
             return
         new_cap = cap
-        while (peak * skew + margin > new_cap * shards
-               and new_cap < self._cap_max):
+        while peak + margin > new_cap and new_cap < self._cap_max:
             new_cap *= 2
         if new_cap == cap:
             return  # at the ceiling; the overflow accounting stands guard
@@ -2063,14 +1719,15 @@ class MicroBatchRuntime:
         """The growth inequality on the CURRENT (possibly ring-stale)
         stats — the step loop's growth-pressure flush trigger: when true,
         flush first (fresh stats), then let _maybe_grow decide."""
-        agg = self._agg()
+        return (self._active_peak() + self._grow_margin()
+                > self._agg().capacity_per_shard)
+
+    def _active_peak(self) -> int:
+        """Live groups the growth rule sizes the slab for: the hottest
+        shard's peak on the partitioned mesh, the slab's own otherwise."""
         if self._parted is not None:
-            return (self._shard_active_peak + self._grow_margin()
-                    > agg.capacity_per_shard)
-        shards = agg.n_shards
-        skew = 2 if shards > 1 else 1
-        return (self._n_active_peak * skew + self._grow_margin()
-                > agg.capacity_per_shard * shards)
+            return self._shard_active_peak
+        return self._n_active_peak
 
     # ------------------------------------------------------- governor
     def _warm_ladder(self, ladder) -> None:
@@ -2287,23 +1944,20 @@ class MicroBatchRuntime:
                 "ts": self._pad(cols.ts_s),
                 "valid": valid,
             }
-        # host pre-snap (HEATMAP_H3_IMPL=native), shared by both paths
+        # host pre-snap (HEATMAP_H3_IMPL=native)
         with span("snap", spans):
-            agg = self._multi if self._multi is not None else self._sharded
             prekeys = self._presnap(feed["lat"], feed["lng"], valid, cols,
-                                    agg._uniq_res, shard_cells=shard_cells)
+                                    self._multi._uniq_res,
+                                    shard_cells=shard_cells)
         with span("transfer", spans):
-            if self._multi is not None:
-                # dispatch the H2D transfers NOW (device_put is async): by
-                # the time this batch is folded, its lanes are already
-                # device-resident — from the prefetch stage the transfer
-                # overlaps the previous batch's fold (double buffering).
-                # The sharded path keeps host arrays: its step applies the
-                # mesh shardings itself (ShardedAggregator._puts).
-                feed = {k: jax.device_put(v) for k, v in feed.items()}
-                if prekeys is not None:
-                    prekeys = {r: (jax.device_put(hi), jax.device_put(lo))
-                               for r, (hi, lo) in prekeys.items()}
+            # dispatch the H2D transfers NOW (device_put is async): by
+            # the time this batch is folded, its lanes are already
+            # device-resident — from the prefetch stage the transfer
+            # overlaps the previous batch's fold (double buffering).
+            feed = {k: jax.device_put(v) for k, v in feed.items()}
+            if prekeys is not None:
+                prekeys = {r: (jax.device_put(hi), jax.device_put(lo))
+                           for r, (hi, lo) in prekeys.items()}
         return _FeedBatch(cols=cols, n=n, feed=feed, prekeys=prekeys,
                           offset=offset, carried=carried, spans=spans,
                           lineage=lin, wm_ts=wm_ts)
@@ -2318,7 +1972,7 @@ class MicroBatchRuntime:
             entry = self._prefetched.popleft()
         else:
             entry = self._next_batch()
-        if entry is None and not self._multiproc:
+        if entry is None:
             # idle poll: settle the parked batches so stats/sink catch up
             self.flush_pending()
             if self.governor is not None:
@@ -2327,18 +1981,6 @@ class MicroBatchRuntime:
                 for gov in self._mesh_governors:
                     gov.note_idle()
             return False
-        if entry is None:
-            # multi-host lockstep: peers may have events and are entering
-            # the global collectives this step — participate with an
-            # all-invalid batch (also keeps watermark eviction ticking)
-            zf = np.zeros(self._feed_batch, np.float32)
-            entry = _FeedBatch(
-                cols=None, n=0,
-                feed={"lat": zf, "lng": zf, "speed": zf,
-                      "ts": np.zeros(self._feed_batch, np.int32),
-                      "valid": np.zeros(self._feed_batch, bool)},
-                prekeys=None, offset=self.source.offset(),
-                carried=self._carry_cols is not None, spans={})
         cols, n, feed = entry.cols, entry.n, entry.feed
 
         # Deferred-pull window: parked batches are pulled when the emit
@@ -2422,8 +2064,7 @@ class MicroBatchRuntime:
         # poll/decode/pad and its device_put run while the device folds
         # the batch just dispatched (the double-buffered feed)
         with span("prefetch", step_spans):
-            if (self._prefetch_n and not self._multiproc
-                    and not self._closing):
+            if self._prefetch_n and not self._closing:
                 while len(self._prefetched) < self._prefetch_n:
                     nxt = self._next_batch()
                     if nxt is None:
@@ -2470,52 +2111,28 @@ class MicroBatchRuntime:
         # last record — a nonzero flag points at the incident window
         # either way.
         c = self.metrics.counters
-        cum = (c.get("events_late", 0), c.get("state_overflow_groups", 0),
-               c.get("events_bucket_dropped", 0))
-        last = getattr(self, "_trace_cum", (0, 0, 0))
+        cum = (c.get("events_late", 0), c.get("state_overflow_groups", 0))
+        last = getattr(self, "_trace_cum", (0, 0))
         self._trace_cum = cum
         self.tracering.record(
             self.epoch - 1, t_end - t0, spans, n_events=n,
-            n_late=cum[0] - last[0], overflow_groups=cum[1] - last[1],
-            late_dropped=cum[2] - last[2])
-        progressed = cols is not None
-        carrying = self._carried_last
-        if self._multiproc:
-            # fixed-position collective: every host contributes
-            # (had-events, still-live, mid-carry); the summed triple is
-            # identical everywhere, so all hosts take the same run()-loop
-            # branch AND the same checkpoint-skip decision (a one-sided
-            # skip would deadlock the checkpoint barrier)
-            had, live, carry_any = self._gpair(
-                float(progressed),
-                0.0 if self.source.exhausted else 1.0,
-                float(carrying))
-            self._global_live = live
-            progressed = had > 0
-            carrying = carry_any > 0
+            n_late=cum[0] - last[0], overflow_groups=cum[1] - last[1])
         if self.checkpoint_every and self.epoch % self.checkpoint_every == 0:
             # cadence hit; if mid-carry, the flag holds the commit until
             # the FIRST carry-free step (a fixed record:feed size ratio can
             # make "cadence epoch AND carry-free" never align, so waiting
             # for the next cadence hit could starve checkpoints forever)
             self._ckpt_due = True
-        if self._ckpt_due and not carrying:
+        if self._ckpt_due and not self._carried_last:
             self._ckpt_due = False
             self._checkpoint()
-        return progressed
+        return True
 
     def _dispatch(self, entry: "_FeedBatch", cutoff: int) -> None:
         """Hand one feed batch to the device fold (async) and book it:
         lineage, the parked emit ring entry, governor and audit counts,
         offsets, and the host-side watermark advance."""
         cols, n, feed = entry.cols, entry.n, entry.feed
-        prekeys = entry.prekeys
-        if cols is None and self._host_snap is not None:
-            # idle lockstep batch under the native snap: cached zero keys
-            agg_ = (self._multi if self._multi is not None
-                    else self._sharded)
-            prekeys = self._presnap(feed["lat"], feed["lng"],
-                                    feed["valid"], None, agg_._uniq_res)
         lin = entry.lineage
         if lin is not None:
             # lineage: the batch leaves the prefetch queue and enters
@@ -2557,23 +2174,14 @@ class MicroBatchRuntime:
                 # the batch's lineage closes when its LAST shard entry
                 # flushes (per-shard flushes run independently)
                 self._mesh_epoch_pend[self.epoch] = n_entries
-        elif self._multi is not None:
+        else:
             # fused path: one dispatch for every (res, window) pair; the
             # packed emits + stats park in the device-resident ring and
             # cross the link in one pull per flush interval (engine.multi
             # + engine.step.EmitRing)
             packed = self._multi.step_packed_all(
                 feed["lat"], feed["lng"], feed["speed"], feed["ts"],
-                feed["valid"], cutoff, prekeys=prekeys)
-            self._ring.append(packed, self.epoch)
-        else:
-            # sharded path: ONE dispatch folds every pair (single fused
-            # all_to_all); the deferred pull covers this host's emit
-            # shards AND the replicated stats for all pairs (packed head
-            # rows; parallel.sharded)
-            packed = self._sharded.step_packed(
-                feed["lat"], feed["lng"], feed["speed"], feed["ts"],
-                feed["valid"], cutoff, prekeys=prekeys)
+                feed["valid"], cutoff, prekeys=entry.prekeys)
             self._ring.append(packed, self.epoch)
         if self.governor is not None:
             self.governor.note_dispatch(n)
@@ -2593,13 +2201,10 @@ class MicroBatchRuntime:
             # process's memory.  The snapshot is the entry's own: the
             # prefetch stage may have polled the source further ahead.
             self._offsets_dispatched = entry.offset
-        if cols is not None and not self._multiproc:
+        if cols is not None:
             # host-side watermark advance (exact device-mask replica):
             # keeps the cutoff batch-granular while the emit pull runs
-            # up to K batches behind (_host_batch_max_ts).  Multi-host
-            # keeps the flush-time advance: its watermark must derive
-            # from the REPLICATED stats, not this host's local rows.
-            # Sharded runs advance from the PRE-ownership-filter rows
+            # up to K batches behind (_host_batch_max_ts).  Sharded runs advance from the PRE-ownership-filter rows
             # (entry.wm_ts): the watermark tracks the full stream, not
             # just this shard's cells.
             bm = self._host_batch_max_ts(
@@ -2618,10 +2223,9 @@ class MicroBatchRuntime:
 
     def _presnap(self, lat, lng, valid, cols, uniq_res, shard_cells=None):
         """Host C++ cell keys for this batch (HEATMAP_H3_IMPL=native), or
-        None for the in-program snap.  Idle lockstep batches (cols is
-        None, all rows invalid — the keys get masked to EMPTY anyway)
-        feed cached zero keys so multi-host idle polls pay no snap, and
-        only the LIVE PREFIX of a padded feed is snapped (an underfilled
+        None for the in-program snap.  All-invalid warm-up batches (cols
+        is None — the keys get masked to EMPTY anyway) feed cached zero
+        keys, and only the LIVE PREFIX of a padded feed is snapped (an underfilled
         poll must not pay the full-batch cost per resolution).
 
         ``shard_cells`` are the ownership filter's native-snapped uint64
@@ -2703,36 +2307,15 @@ class MicroBatchRuntime:
         return blocks
 
     def _mesh_chunk(self, cols, sel, cells, bucket: int, d: int) -> dict:
-        """One device's padded feed chunk: lanes gathered by ``sel``
-        (owned-row indices, stream order), padded to ``bucket``, host
-        pre-snap keys attached (reusing the partition's own cells for
-        the coarsest resolution — the PR 7 handoff), everything
-        committed to device ``d``."""
+        """One device's padded feed chunk (MeshPartition.block: lanes
+        gathered by ``sel``, owned-row indices in stream order, padded
+        to ``bucket``, host pre-snap keys reusing the partition's own
+        cells — the PR 7 handoff), everything committed to device
+        ``d``."""
+        (lat, lng, speed, ts, valid), prekeys = self.meshmap.block(
+            (cols.lat_rad, cols.lng_rad, cols.speed_kmh, cols.ts_s), sel,
+            cells, bucket, self._parted._uniq_res)
         n = int(sel.size)
-        lat = np.zeros(bucket, np.float32)
-        lat[:n] = cols.lat_rad[sel]
-        lng = np.zeros(bucket, np.float32)
-        lng[:n] = cols.lng_rad[sel]
-        speed = np.zeros(bucket, np.float32)
-        speed[:n] = cols.speed_kmh[sel]
-        ts = np.zeros(bucket, np.int32)
-        ts[:n] = cols.ts_s[sel]
-        valid = np.zeros(bucket, bool)
-        valid[:n] = True
-        prekeys = None
-        if self._host_snap is not None:
-            sub_cells = (cells[sel] if (cells is not None
-                                        and self.meshmap.native) else None)
-            prekeys = {}
-            for r in self._parted._uniq_res:
-                hi = np.zeros(bucket, np.uint32)
-                lo = np.zeros(bucket, np.uint32)
-                if sub_cells is not None and r == self.meshmap.snap_res:
-                    hi[:n] = (sub_cells >> np.uint64(32)).astype(np.uint32)
-                    lo[:n] = sub_cells.astype(np.uint32)
-                else:
-                    hi[:n], lo[:n] = self._host_snap(lat[:n], lng[:n], r)
-                prekeys[r] = (hi, lo)
         dev = self._parted.devices[d]
         feed = {"lat": jax.device_put(lat, dev),
                 "lng": jax.device_put(lng, dev),
@@ -3016,11 +2599,9 @@ class MicroBatchRuntime:
                 # included, so a quiet stream still reads as alive at
                 # /fleet/healthz instead of going stale)
                 self._publish_member_snapshot()
-                done = (self._global_live == 0 if self._multiproc
-                        else self.source.exhausted)
                 if progressed:
                     n += 1
-                elif done:
+                elif self.source.exhausted:
                     break
                 else:
                     time.sleep(0.05)
@@ -3092,17 +2673,10 @@ class MicroBatchRuntime:
                 # drain any carry AND any prefetched-but-undispatched
                 # batches so the exit commit is record-aligned and a
                 # bounded run loses nothing it already consumed from the
-                # source.  Multiproc does NOT drain here (extra local
-                # steps would desync the lockstep collectives;
-                # run(max_batches=N) CAN exit mid-carry) — instead
-                # _checkpoint() decides the mid-carry skip collectively,
-                # so a carrying host and its carry-free peers all skip
-                # the exit commit together and the tail replays on
-                # resume.  On a fatal/poisoned exit the commit is skipped
+                # source.  On a fatal/poisoned exit the commit is skipped
                 # anyway and the uncommitted carry replays on resume —
                 # don't dispatch into a failed run.
                 while ((self._carry_cols is not None or self._prefetched)
-                       and not self._multiproc
                        and not self._fatal and not self.writer.poisoned):
                     self._step_once_inner()
                 self.flush_pending()

@@ -24,16 +24,14 @@ Knobs (flat env, read by ``config.load_config``):
   balance, still exact).  Must not exceed the snap resolution.
 
 Exactness contract (what the differential test pins): the partitioner
-snaps each event at the COARSEST configured fold resolution with the
-same host snap the fold itself uses, so for single-resolution configs
-(any window set) every (cell, window) group lands wholly in one shard
-and the N-shard merged emits are byte-identical to the 1-shard fold.
-Multi-resolution pyramids partition by the coarsest resolution's cell
-space; finer-resolution cells straddling a partition-parent boundary
-(H3 children are not geometrically contained in their parents) may
-split across shards — the merged view then upserts per shard, which is
-bounded drift on boundary slivers, not corruption, and is documented
-in ARCHITECTURE.md §Sharded runtime.
+snaps each event at the fold resolution with the same host snap the
+fold itself uses, so every (cell, window) group lands wholly in one
+shard (any window set) and the N-shard merged emits are byte-identical
+to the 1-shard fold.  That holds for one resolution only: H3 children
+are not geometrically contained in their parents, so a partition by
+one resolution's cells would split cells of another resolution over two
+shards.  A multi-resolution pyramid is therefore refused on a fleet
+(config.load_config) and on a mesh (parallel.PartitionedAggregator).
 """
 
 from __future__ import annotations
@@ -213,7 +211,7 @@ class ShardMap:
 
 class MeshPartition:
     """Stable H3-parent → mesh-device assignment for the partitioned
-    mesh fast path (parallel.sharded.PartitionedAggregator).
+    mesh fast path (parallel.PartitionedAggregator).
 
     Same exactness contract as :class:`ShardMap` — the partition key is
     the H3 parent (bit surgery) of the event's cell snapped at the
@@ -221,8 +219,7 @@ class MeshPartition:
     through murmur3 fmix64: a pure, stable function of the cell index,
     so every (cell, window) group lands wholly on one device and the
     merged per-device emits are byte-identical to the single-device
-    fold (single-resolution configs; multi-res pyramids carry the same
-    bounded boundary-sliver caveat ShardMap documents).
+    fold (one resolution; a multi-resolution pyramid is refused).
 
     ``outer_shards`` composes with PROCESS-level H3 sharding
     (HEATMAP_SHARDS): a shard process already filtered its rows by
@@ -285,6 +282,42 @@ class MeshPartition:
         if cells is None:
             cells = self.cells_of(lat_rad, lng_rad)
         return self.device_of_cells(cells), cells
+
+    def block(self, lanes, sel: np.ndarray, cells: np.ndarray,
+              bucket: int, resolutions) -> tuple:
+        """One device's feed chunk on the host: the rows ``sel``
+        (owned-row indices, in stream order) of ``lanes`` = (lat_rad,
+        lng_rad, speed_kmh, ts_s) compacted to the front of ``bucket``
+        zero-padded rows, and the ``valid`` mask, as ``(lat, lng,
+        speed, ts, valid)``; plus pre-snap keys ``{res: (hi, lo)}`` for
+        each of ``resolutions``, where the batch's partition ``cells``
+        are reused at ``snap_res`` (no second snap), or None without
+        the native snap."""
+        n = int(sel.size)
+        out = []
+        for a, dtype in zip(lanes, (np.float32, np.float32, np.float32,
+                                    np.int32)):
+            col = np.zeros(bucket, dtype)
+            col[:n] = a[sel]
+            out.append(col)
+        valid = np.zeros(bucket, bool)
+        valid[:n] = True
+        out.append(valid)
+        if self._host_snap is None:
+            return tuple(out), None
+        lat, lng = out[0], out[1]
+        prekeys = {}
+        for r in resolutions:
+            hi = np.zeros(bucket, np.uint32)
+            lo = np.zeros(bucket, np.uint32)
+            if r == self.snap_res:
+                own = cells[sel]
+                hi[:n] = (own >> np.uint64(32)).astype(np.uint32)
+                lo[:n] = own.astype(np.uint32)
+            else:
+                hi[:n], lo[:n] = self._host_snap(lat[:n], lng[:n], r)
+            prekeys[r] = (hi, lo)
+        return tuple(out), prekeys
 
     def describe(self) -> str:
         return (f"{self.n_devices}-device mesh partition (snap res "

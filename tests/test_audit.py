@@ -104,14 +104,13 @@ def test_drop_reason_set_is_closed():
     from heatmap_tpu.stream.metrics import DROP_REASONS, Metrics
 
     assert DROP_REASONS == ("invalid", "late", "out_of_shard",
-                            "oversample", "exchange", "handoff")
+                            "oversample", "handoff")
     m = Metrics()
     for r in DROP_REASONS:
         m.drop(r, 2)
     assert m.counters["events_invalid"] == 2
     assert m.counters["events_late"] == 2
     assert m.counters["events_out_of_shard"] == 4  # + oversample
-    assert m.counters["events_bucket_dropped"] == 2
     assert m.counters["infer_handoff_reseed"] == 2
     text = m.registry.expose_text()
     for r in DROP_REASONS:
@@ -128,7 +127,7 @@ def test_drop_forwards_to_audit_ledger():
     aud = AuditState(tag="t")
     m.audit = aud
     m.drop("invalid", 3)
-    m.drop("exchange", 5, audit=False)  # secondary-pair drops stay out
+    m.drop("late", 5, audit=False)  # secondary-pair drops stay out
     assert aud.counts() == {"dropped_invalid": 3}
 
 

@@ -377,12 +377,11 @@ def test_governed_runtime_wiring(tmp_path):
     rt.close()
 
 
-def test_govern_skipped_on_multihost_style_paths(tmp_path):
-    """The governor only runs the single-device fused path; a mesh /
-    multi-host runtime ignores HEATMAP_GOVERN with a warning rather
-    than desyncing lockstep accounting.  (Cheap proxy: the unsharded
-    CPU runtime HAS a governor; the attribute contract is what the
-    step loop guards on.)"""
+def test_no_governor_when_govern_off(tmp_path):
+    """With HEATMAP_GOVERN off the runtime builds no governor: the
+    attribute contract the step loop guards on.  (A partitioned mesh
+    governs one BatchGovernor per device instead; test_mesh_diff
+    covers it.)"""
     cfg = load_config({}, batch_size=128, state_capacity_log2=10,
                       speed_hist_bins=4, store="memory", govern=False,
                       checkpoint_dir=str(tmp_path / "nogov"))

@@ -236,8 +236,7 @@ def test_engine_auto_merge_consults_bank(monkeypatch, tmp_path):
 
 
 def test_merge_bank_pin_overrides_live_consult(monkeypatch, tmp_path):
-    """A frozen MERGE_BANK_PIN of None (the multihost collective's
-    bank-disagreement demotion, or a no-bank runtime snapshot) sends
+    """A frozen MERGE_BANK_PIN of None (a no-bank runtime snapshot) sends
     `auto` to the static rule even with a valid live bank present —
     merge_batch must not re-read the file once a runtime pinned it."""
     from heatmap_tpu.engine import step as engine_step

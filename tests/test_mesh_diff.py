@@ -27,6 +27,7 @@ with merged emits byte-identical to the ungoverned mesh).
 """
 
 import copy
+import functools
 import time
 
 import numpy as np
@@ -42,23 +43,27 @@ BATCH = 256
 N_DEV = 4
 
 
+def _event(rng, i, t, lat=None, lon=None):
+    """One event of vehicle ``i % 37`` at time ``t``, placed uniformly
+    over a wide box unless ``lat``/``lon`` are given."""
+    v = i % 37
+    return {
+        "provider": "mbta" if v % 3 else "opensky",
+        "vehicleId": f"veh-{v}",
+        "lat": float(rng.uniform(42.3, 42.5)) if lat is None else lat,
+        "lon": float(rng.uniform(-71.2, -71.0)) if lon is None else lon,
+        "speedKmh": float(rng.uniform(0, 80)),
+        "bearing": 0.0,
+        "accuracyM": 5.0,
+        "ts": t,
+    }
+
+
 def mk_stream():
     """The test_shard_diff hazard stream: wide box (all shards own
     cells), invalid rows, duplicates, hour-late rows."""
     rng = np.random.default_rng(11)
-
-    def ev(i, t, lat=None, lon=None):
-        v = i % 37
-        return {
-            "provider": "mbta" if v % 3 else "opensky",
-            "vehicleId": f"veh-{v}",
-            "lat": float(rng.uniform(42.3, 42.5)) if lat is None else lat,
-            "lon": float(rng.uniform(-71.2, -71.0)) if lon is None else lon,
-            "speedKmh": float(rng.uniform(0, 80)),
-            "bearing": 0.0,
-            "accuracyM": 5.0,
-            "ts": t,
-        }
+    ev = functools.partial(_event, rng)
 
     out = [ev(i, T_NOW + i % 120) for i in range(3 * BATCH)]
     out += [
@@ -74,11 +79,68 @@ def mk_stream():
     return out
 
 
+def mk_late_band():
+    """On-time traffic, then a band of rows straddling the watermark
+    (10 minutes behind the newest event) and an hour past it,
+    interleaved with on-time rows, then on-time traffic again."""
+    rng = np.random.default_rng(12)
+    out = [_event(rng, i, T_NOW + i % 120) for i in range(2 * BATCH)]
+    for i in range(2 * BATCH):
+        if i % 3 == 0:
+            t = T_NOW - 3600 + i % 300          # an hour late
+        elif i % 3 == 1:
+            t = T_NOW - 900 + i % 600           # across the cutoff
+        else:
+            t = T_NOW + 120 + i % 60            # on time
+        out.append(_event(rng, i, t))
+    out += [_event(rng, i, T_NOW + 200 + i % 60) for i in range(BATCH)]
+    return out
+
+
+def mk_invalid():
+    """Two rows in five invalid, of every kind the parser drops, spread
+    through every batch."""
+    rng = np.random.default_rng(13)
+    bad = [dict(lat=95.0), dict(lon=-200.0), dict(lat=float("nan")),
+           dict(lon=float("inf")), dict(t=-5)]
+    out = []
+    for i in range(4 * BATCH):
+        if i % 5 < 2:
+            kw = dict(bad[(i // 5) % len(bad)])
+            out.append(_event(rng, i, kw.pop("t", T_NOW + i % 120), **kw))
+        else:
+            out.append(_event(rng, i, T_NOW + i % 120))
+    return out
+
+
+def mk_zipf_hubs():
+    """Zipf cell occupancy over 1024 hubs: the first hub takes over a
+    third of all rows, so the device that owns it runs hot."""
+    rng = np.random.default_rng(14)
+    n, n_hubs = 4 * BATCH, 1024
+    p = 1.0 / np.arange(1, n_hubs + 1) ** 1.5
+    hub_lat = rng.uniform(42.3, 42.5, n_hubs)
+    hub_lon = rng.uniform(-71.2, -71.0, n_hubs)
+    pick = rng.choice(n_hubs, size=n, p=p / p.sum())
+    return [_event(rng, i, T_NOW + i % 120,
+                   lat=float(hub_lat[h] + rng.uniform(-1e-5, 1e-5)),
+                   lon=float(hub_lon[h] + rng.uniform(-1e-5, 1e-5)))
+            for i, h in enumerate(pick)]
+
+
+def mk_turnover():
+    """An hour of event time in stream order, about ten minutes per
+    batch: every batch closes windows and the watermark evicts them."""
+    rng = np.random.default_rng(15)
+    n = 6 * BATCH
+    return [_event(rng, i, T_NOW - 3600 + i * 3600 // n) for i in range(n)]
+
+
 def run_one(tmp_path, events, tag, mesh=None, flush_k=3, govern=False,
             max_batches=None, checkpoint_every=0, source=None,
-            store=None, **over):
+            store=None, env=None, **over):
     cfg = load_config(
-        {}, batch_size=BATCH, state_capacity_log2=12, speed_hist_bins=8,
+        env or {}, batch_size=BATCH, state_capacity_log2=12, speed_hist_bins=8,
         store="memory", emit_flush_k=flush_k, govern=govern,
         govern_min_batch=64, checkpoint_dir=str(tmp_path / f"ckpt-{tag}"),
         **over)
@@ -99,13 +161,32 @@ def assert_stores_equal(s1, sN):
     assert s1._positions == sN._positions
 
 
-def test_one_vs_mesh_byte_identical(tmp_path):
-    events = mk_stream()
-    rt1, s1 = run_one(tmp_path, events, "base")
-    rtN, sN = run_one(tmp_path, events, "mesh", mesh=make_mesh(N_DEV))
+def _live_groups(rt) -> int:
+    """Live (cell, window) groups left in every pair's slab."""
+    return sum(int(np.count_nonzero(
+        np.asarray(agg.snapshot().key_hi) != np.uint32(0xFFFFFFFF)))
+        for agg in rt.aggs.values())
 
-    assert rtN._parted is not None, "auto mode must pick partitioned"
-    assert rtN._mesh_mode == "partitioned"
+
+TRAFFIC = {
+    "hazard": (mk_stream, None),
+    "late_band": (mk_late_band, None),
+    "invalid": (mk_invalid, None),
+    "zipf_hubs": (mk_zipf_hubs, None),
+    "turnover": (mk_turnover, None),
+    "windows": (mk_stream, {"WINDOW_MINUTES": "1,5,15"}),
+}
+
+
+@pytest.mark.parametrize("traffic", list(TRAFFIC))
+def test_one_vs_mesh_byte_identical(tmp_path, traffic):
+    make, env = TRAFFIC[traffic]
+    events = make()
+    rt1, s1 = run_one(tmp_path, events, "base", env=env)
+    rtN, sN = run_one(tmp_path, events, "mesh", mesh=make_mesh(N_DEV),
+                      env=env)
+
+    assert rtN._parted is not None and rtN._mesh_mode == "partitioned"
     assert len(s1._tiles) > 100                 # a real city's worth
     assert_stores_equal(s1, sN)
 
@@ -117,19 +198,47 @@ def test_one_vs_mesh_byte_identical(tmp_path):
         assert c1.get(key, 0) == cN.get(key, 0), key
     # the watermark tracks the FULL stream (pre-partition rows)
     assert rt1.max_event_ts == rtN.max_event_ts
-    # every shard folded something on the wide box, and the ring
-    # amortized: pulls <= ceil(batches/K) + 1 forced close flush per
-    # shard, far below one pull per (shard, batch)
+    # every shard folded something, and every shard's ring saw every
+    # batch (empty blocks dispatch too)
     stats = rtN.mesh_shard_stats()
     assert len(stats) == N_DEV
     assert all(m["rows"] > 0 for m in stats)
     n_batches = rtN.epoch
     for m in stats:
-        assert m["emit_pulls"] <= -(-n_batches // 3) + 1, m
         assert m["emit_pull_batches"] == n_batches, m
     # zero post-warmup retraces across every per-device program
     assert rtN.runtimeinfo.compile.snapshot()["retraces_after_warmup"] \
         == 0
+
+    if traffic == "hazard":
+        # the ring amortized: pulls <= ceil(batches/K) + 1 forced close
+        # flush per shard, far below one pull per (shard, batch)
+        for m in stats:
+            assert m["emit_pulls"] <= -(-n_batches // 3) + 1, m
+    elif traffic == "late_band":
+        assert c1["events_late"] > BATCH // 2
+    elif traffic == "invalid":
+        assert c1["events_invalid"] == sum(
+            i % 5 < 2 for i in range(len(events)))
+    elif traffic == "zipf_hubs":
+        # each shard folded exactly the rows the partition gives it
+        from heatmap_tpu.stream.events import parse_events
+
+        cols = parse_events(events, {}, {})
+        ids, _ = rtN.meshmap.partition(cols.lat_rad, cols.lng_rad)
+        want = np.bincount(ids, minlength=N_DEV).tolist()
+        assert [m["rows"] for m in stats] == want
+        assert max(want) > 1.5 * len(events) / N_DEV, "one shard hot"
+    elif traffic == "turnover":
+        windows = {k.split("|")[3] for k in s1._tiles}
+        assert len(windows) >= 10
+        # closed windows left both slabs alike
+        assert 0 < _live_groups(rtN) == _live_groups(rt1) \
+            < len(s1._tiles)
+    elif traffic == "windows":
+        # three pairs fused in each device's program, one grid each
+        assert len(rtN.aggs) == 3
+        assert len({k.split("|")[1] for k in s1._tiles}) == 3
 
 
 def test_mesh_resume_mid_ring_byte_identical(tmp_path):
@@ -170,17 +279,149 @@ def test_mesh_resume_mid_ring_byte_identical(tmp_path):
     assert_stores_equal(s1, store)
 
 
-def test_mesh_mode_checkpoint_refuses_cross_mode_restore(tmp_path):
-    """A partitioned-mode checkpoint must not restore into a
-    shuffle-mode run (same block layout, different key ownership)."""
-    import pytest
+@pytest.mark.parametrize("ck_mode", ["shuffle", None],
+                         ids=["shuffle", "pre_mode"])
+def test_mesh_mode_checkpoint_refuses_cross_mode_restore(tmp_path,
+                                                         ck_mode):
+    """A checkpoint of the removed ICI-shuffle mesh — marked "shuffle",
+    or a multi-shard meta written before checkpoints recorded their
+    mode — keyed its shard blocks by a key hash, not by H3 cell: the
+    partitioned mesh refuses to resume it and says to clear the
+    checkpoint directory."""
+    import glob
+    import json
 
     events = mk_stream()[:BATCH]
     mesh = make_mesh(2)
     run_one(tmp_path, events, "xmode", mesh=mesh, checkpoint_every=1)
-    with pytest.raises(RuntimeError, match="mesh mode"):
-        run_one(tmp_path, events, "xmode", mesh=mesh,
-                mesh_partitioned="0")
+    metas = glob.glob(str(tmp_path / "ckpt-xmode" / "commit-*"
+                          / "meta.json"))
+    assert metas
+    for path in metas:
+        with open(path) as fh:
+            meta = json.load(fh)
+        assert meta["mesh_mode"] == "partitioned"
+        if ck_mode is None:
+            del meta["mesh_mode"]
+        else:
+            meta["mesh_mode"] = ck_mode
+        with open(path, "w") as fh:
+            json.dump(meta, fh)
+    with pytest.raises(RuntimeError, match="mesh mode 'shuffle'.*clear"):
+        run_one(tmp_path, events, "xmode", mesh=mesh)
+
+
+def test_runtime_refuses_a_multi_process_jax_runtime(tmp_path,
+                                                     monkeypatch):
+    """One runtime serves one JAX process: on a multi-process JAX
+    runtime it refuses to start and names the fleet deployment that
+    replaces it (one runtime per host, split by HEATMAP_SHARDS)."""
+    import jax
+
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    cfg = load_config({}, batch_size=BATCH, state_capacity_log2=12,
+                      store="memory", checkpoint_dir=str(tmp_path / "c"))
+    with pytest.raises(RuntimeError, match="one runtime per host.*"
+                                           "HEATMAP_SHARDS"):
+        MicroBatchRuntime(cfg, MemorySource([]), MemoryStore(),
+                          mesh=make_mesh(2))
+
+
+@pytest.mark.parametrize("fold", ["mesh", "fleet"])
+def test_multi_resolution_pyramid_refused_on_a_partitioned_stream(
+        tmp_path, fold):
+    """H3 children are not contained in their parents, so a partition
+    by one resolution's cells splits cells of the pyramid's other
+    resolutions over two folds, each then counting part of a group.
+    Both partitioned deployments refuse a pyramid at start-up and say
+    how to run it: a mesh when the runtime builds its aggregator, a
+    HEATMAP_SHARDS fleet when the config loads."""
+    env = {"H3_RESOLUTIONS": "7,8,9"}
+    if fold == "fleet":
+        env.update(HEATMAP_SHARDS="2", HEATMAP_SHARD_INDEX="0")
+        with pytest.raises(ValueError, match="multi-resolution pyramid.*"
+                                             "2-process HEATMAP_SHARDS"):
+            load_config(env)
+        return
+    cfg = load_config(env, batch_size=BATCH, state_capacity_log2=12,
+                      store="memory", checkpoint_dir=str(tmp_path / "c"))
+    with pytest.raises(ValueError, match="multi-resolution pyramid.*"
+                                         "4-device mesh.*one device"):
+        MicroBatchRuntime(cfg, MemorySource([]), MemoryStore(),
+                          mesh=make_mesh(N_DEV))
+
+
+def test_pyramid_pipeline_folds_on_one_device_by_default(tmp_path):
+    """``python -m heatmap_tpu.stream hex_pyramid`` on a host of several
+    devices builds no mesh unless asked (a mesh would refuse the
+    pyramid); asking for one is refused."""
+    import dataclasses
+
+    from heatmap_tpu.models.pipelines import build_runtime, get_pipeline
+
+    p = get_pipeline("hex_pyramid")
+    p = dataclasses.replace(p, config=dataclasses.replace(
+        p.config, store="memory", checkpoint_dir=str(tmp_path / "c")))
+    rt, _ = build_runtime(p, source=MemorySource([]))
+    assert rt._mesh_mode is None and rt._parted is None
+    rt.close()
+    with pytest.raises(ValueError, match="multi-resolution pyramid"):
+        build_runtime(p, source=MemorySource([]), n_devices=2)
+
+
+@pytest.mark.parametrize("var", ["HEATMAP_COORDINATOR",
+                                 "HEATMAP_NUM_PROCESSES",
+                                 "HEATMAP_PROCESS_ID"])
+def test_removed_multi_host_variables_are_refused(var):
+    """A pod started the old multi-process way would run each host as
+    an independent runtime writing partial tiles to the shared store:
+    each removed variable is refused when the config loads, with the
+    fleet deployment that replaces it."""
+    with pytest.raises(ValueError, match=f"{var}.*one runtime per host.*"
+                                         "HEATMAP_SHARDS"):
+        load_config({var: "0"})
+    assert load_config({var: ""}).shards == 1
+
+
+@pytest.mark.parametrize("value", ["auto", "1", "0"])
+def test_mesh_partitioned_accepts_only_the_partitioned_path(value):
+    """``mesh_partitioned`` is kept so configs that set it still load:
+    auto and 1 both mean the partitioned mesh; 0 asked for the removed
+    shuffle path and is refused."""
+    if value == "0":
+        with pytest.raises(ValueError, match="removed"):
+            load_config({}, mesh_partitioned=value)
+    else:
+        assert load_config({}, mesh_partitioned=value).mesh_partitioned \
+            == value
+
+
+def test_process_shards_over_partitioned_meshes_match_one_chip(tmp_path):
+    """The deployment that serves a pod: one runtime per host, split by
+    HEATMAP_SHARDS, each partitioning its own cells over its own
+    chips.  Two process shards, each over a 2-device mesh of its own
+    and polling one batch of the stream at a time (oversample 1, the
+    batch boundaries of one chip), write one store whose tiles are
+    byte-identical to one chip's."""
+    import jax
+
+    events = mk_stream()
+    _, s1 = run_one(tmp_path, events, "fleet1")
+    store = MemoryStore()
+    devs = jax.devices()
+    fleet = [run_one(tmp_path, events, f"fleet-s{i}", store=store,
+                     mesh=make_mesh(devices=devs[2 * i:2 * i + 2]),
+                     shards=2, shard_index=i, shard_oversample=1)[0]
+             for i in range(2)]
+    for rt in fleet:
+        assert rt._mesh_mode == "partitioned"
+        assert rt.metrics.counters.get("events_out_of_shard", 0) > 0
+        assert all(m["rows"] > 0 for m in rt.mesh_shard_stats())
+    assert len(s1._tiles) > 100
+    assert s1._tiles.keys() == store._tiles.keys()
+    for k in s1._tiles:
+        assert s1._tiles[k] == store._tiles[k], k
+    assert s1._positions == store._positions
 
 
 def test_hot_cold_flush_independence(tmp_path):
@@ -312,32 +553,6 @@ def test_governed_mesh_shards_converge_apart_results_identical(tmp_path):
             == rt_u.metrics.counters.get(key, 0), key
 
 
-def test_fastpath_pin_surfaces_in_telemetry(tmp_path):
-    """Satellite bugfix: a pinned fast path (multi-host forcing
-    emit_flush_k=1/prefetch=0) must surface as
-    heatmap_fastpath_pinned{reason=} and a /healthz warning check, not
-    just one INFO log line."""
-    from heatmap_tpu.serve.api import healthz_payload
-
-    rt, _ = run_one(tmp_path, mk_stream()[:8], "pin")
-    assert rt._fastpath_pinned == {}
-    before, _ = healthz_payload(rt)
-    assert "fastpath_pinned" not in before["checks"]
-
-    rt._note_fastpath_pinned("multihost_lockstep",
-                             "emit_flush_k 8->1, prefetch_batches 1->0")
-    text = rt.metrics.expose_text()
-    assert 'heatmap_fastpath_pinned{reason="multihost_lockstep"} 1' \
-        in text
-    payload, down = healthz_payload(rt)
-    chk = payload["checks"]["fastpath_pinned"]
-    assert chk["ok"] and chk.get("warn")
-    assert "multihost_lockstep" in chk["value"]
-    # a WARNING, not a degradation: the verdict is whatever it was
-    # before the pin surfaced
-    assert not down and payload["status"] == before["status"]
-
-
 def test_mesh_partition_stability_and_composition():
     """The mesh partition key is a pure function of the cell index —
     stable across instances — and composes with process-level sharding
@@ -424,15 +639,22 @@ def test_partitioned_resume_refuses_in_program_keyed_checkpoint(
                 checkpoint_every=1)
 
 
-def _compile_counter():
+def _compile_counter(this_thread_only: bool = False):
     """Count XLA compile requests (compiled or read from the persistent
-    cache) in this process from now on."""
+    cache) in this process from now on — or, with
+    ``this_thread_only``, only those the calling thread makes (JAX
+    reports a compile request on the thread that requests it)."""
+    import threading
+
     import jax
 
     box = {"n": 0}
+    me = threading.get_ident()
 
     def on_event(event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
+        if (event == "/jax/compilation_cache/compile_requests_use_cache"
+                and not (this_thread_only
+                         and threading.get_ident() != me)):
             box["n"] += 1
 
     jax.monitoring.register_event_listener(on_event)
@@ -463,7 +685,9 @@ def test_mesh_ring_flush_compiles_nothing_after_start_up(tmp_path,
     """After start-up, a flush of each mesh ring at every depth it can
     reach (idle entries park up to 8x its capacity) and, under the
     prefix pull, every emitted-rows bucket, reads programs the runtime
-    compiled at start-up: no compile request."""
+    compiled at start-up: no compile request.  The counter sees only
+    this thread's requests, so threads other tests left in the process
+    cannot reach it."""
     import jax
 
     cfg = load_config(
@@ -488,13 +712,16 @@ def test_mesh_ring_flush_compiles_nothing_after_start_up(tmp_path,
             host[:, 0, 0] = n
             parked.append((d, jax.device_put(host, packed.sharding)))
     cap = rt._mesh_rings[0].capacity
-    compiles = _compile_counter()
-    for d, entry in parked:
-        for depth in range(1, 8 * cap + 1):
-            ring = EmitRing(cap)
-            for _ in range(depth):
-                ring.append(entry, live=False)
-            assert len(rt._pull_mesh_ring(ring)) == depth
+    compiles = _compile_counter(this_thread_only=True)
+    try:
+        for d, entry in parked:
+            for depth in range(1, 8 * cap + 1):
+                ring = EmitRing(cap)
+                for _ in range(depth):
+                    ring.append(entry, live=False)
+                assert len(rt._pull_mesh_ring(ring)) == depth
+    finally:
+        rt.close()
     assert compiles["n"] == 0
 
 

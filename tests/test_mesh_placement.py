@@ -18,13 +18,13 @@ import pytest
 
 from heatmap_tpu.engine.multi import MultiAggregator
 from heatmap_tpu.engine.step import AggParams
-from heatmap_tpu.parallel import make_mesh
-from heatmap_tpu.parallel.sharded import PartitionedAggregator
+from heatmap_tpu.parallel import PartitionedAggregator, make_mesh
 
 N_DEV = 4
 BATCH = 64
 I32_MIN = -(2**31)
-PAIRS = [(9, 300), (8, 300)]
+# one resolution: the partitioned mesh refuses a multi-resolution pyramid
+PAIRS = [(9, 300), (9, 60)]
 
 
 def _lanes(rng):

@@ -586,59 +586,6 @@ def test_end_to_end_per_cell_differential(tmp_path):
         assert cnt == oracle[k][0], k
         assert math.isclose(sum_speed, oracle[k][1], rel_tol=1e-4), k
 
-def test_exit_commit_mid_carry_skip_is_collective(tmp_path, monkeypatch):
-    """Multi-host: a host reaching the exit commit mid-carry must not
-    decide the skip locally — its carry-free peers would block in the
-    commit barrier forever.  _checkpoint() agrees through the gpair
-    collective BEFORE the barrier: if ANY host carries, ALL skip.
-    (Regression: the skip used to early-return on the local carry alone,
-    stranding peers in sync_global_devices when run(max_batches=N) ended
-    with one host mid-carry.)"""
-    from jax.experimental import multihost_utils
-
-    cfg = load_config({}, batch_size=64, store="memory",
-                      checkpoint_dir=str(tmp_path / "ckpt"),
-                      state_capacity_log2=8, speed_hist_bins=0)
-    rt = MicroBatchRuntime(cfg, MemorySource([]), MemoryStore(),
-                           checkpoint_every=0)
-    order = []
-    peer = {"carry": 0.0}
-
-    def gpair(a, b, c):
-        order.append(("gpair", c))
-        return np.array([a, b, c + peer["carry"]], np.float32)
-
-    monkeypatch.setattr(
-        multihost_utils, "sync_global_devices",
-        lambda name: order.append(("barrier", name)))
-    rt._multiproc = True
-    rt._gpair = gpair
-
-    # 1) local mid-record state (the last dispatched batch overshot) ->
-    # collective consulted, commit skipped pre-barrier
-    rt._carried_last = True
-    rt._checkpoint()
-    assert order == [("gpair", 1.0)]
-    assert rt.ckpt.load_meta() is None
-
-    # 2) carry-free host whose PEER carries -> skips too (the agreement)
-    order.clear()
-    rt._carried_last = False
-    peer["carry"] = 1.0
-    rt._checkpoint()
-    assert order == [("gpair", 0.0)]
-    assert rt.ckpt.load_meta() is None
-
-    # 3) nobody carries -> agreement first, then barrier, then commit
-    order.clear()
-    peer["carry"] = 0.0
-    rt._checkpoint()
-    assert [kind for kind, _ in order] == ["gpair", "barrier"]
-    assert rt.ckpt.load_meta() is not None
-    assert rt.metrics.counters["checkpoints"] == 1
-    rt._multiproc = False
-    rt.close()
-
 def test_emit_pull_prefix_equals_full(tmp_path):
     """emit_pull=prefix (the off-CPU auto choice: head rows + live-prefix
     bucket, two transfers) must sink exactly what emit_pull=full sinks —

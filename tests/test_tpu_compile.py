@@ -15,10 +15,8 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
-                          SingleDeviceSharding)
+from jax.sharding import SingleDeviceSharding
 
 HBM_BYTES = 16e9  # one v5e chip
 
@@ -116,29 +114,4 @@ def test_kalman_scan_entity_table(one_chip):
         arr((m, 4), f32), arr((m, 4, 4), f32), arr((k, m, 2), f32),
         arr((k, m), f32), arr((k, m), jnp.bool_), arr((k, m), jnp.bool_),
         *[arr((), f32)] * 5).compile()
-    _fits(compiled)
-
-
-def test_sharded_step_on_v5e_2x2_mesh(topo):
-    """The shard_map step (one all_to_all per batch) on a 4-chip mesh
-    at the default config's widths (batch 2^17, slab 2^17 per shard)."""
-    from heatmap_tpu.engine.step import AggParams
-    from heatmap_tpu.parallel.sharded import (AXIS, exchange_lane_capacity,
-                                              packed_step)
-
-    n, batch, capacity = 4, 1 << 17, 1 << 17
-    mesh = Mesh(np.array(topo.devices[:n]), (AXIS,))
-    rows = NamedSharding(mesh, P(AXIS))
-    rows2 = NamedSharding(mesh, P(AXIS, None))
-    params = [AggParams(res=8, window_s=300, emit_capacity=batch // n)]
-    step = packed_step(mesh, params,
-                       exchange_lane_capacity(batch // n, n, 2.0))
-    st = _state_shapes(n * capacity, 64,
-                       lambda a: rows2 if a.ndim == 2 else rows)
-    f32 = _shape((batch,), jnp.float32, rows)
-    compiled = step.lower(
-        (st,), f32, f32, f32, _shape((batch,), jnp.int32, rows),
-        _shape((batch,), jnp.bool_, rows),
-        _shape((), jnp.int32, NamedSharding(mesh, P()))).compile()
-    assert "all-to-all" in compiled.as_text()
     _fits(compiled)

@@ -627,9 +627,9 @@ def compare_multichip(dir_path: str, threshold: float) -> int:
     if mode_prev != mode_new:
         print(f"FAIL: mesh mode mismatch — multichip r{r_prev:02d} "
               f"ran {mode_prev!r} but r{r_new:02d} ran {mode_new!r}; "
-              f"the partitioned fast path and the ICI-shuffle path "
-              f"are different experiments — re-run in the same "
-              f"HEATMAP_MESH_PARTITIONED mode", file=sys.stderr)
+              f"the partitioned mesh and the removed ICI-shuffle mesh "
+              f"are different experiments — compare artifacts of the "
+              f"same mode", file=sys.stderr)
         return 1
     drop = (rate_prev - rate_new) / rate_prev
     line = (f"multichip r{r_prev:02d} {rate_prev:,.0f} ev/s -> "

@@ -4,7 +4,7 @@
 The README §Configuration tables are the operator contract for the
 flat-env configuration surface.  Nothing kept them honest: at PR 4 the
 code read 46 distinct HEATMAP_* names and the README documented 33 —
-a third of the knobs (multihost bring-up, device probe, profiler,
+a third of the knobs (device probe, profiler,
 native-build cache, heartbeat plumbing) were discoverable only by
 grepping the source.
 

@@ -659,7 +659,7 @@ def render_fleet_frame(m: dict, prev: dict | None, dt: float,
         lines.append(f"  imbalance max/mean "
                      f"{fmt(imbalance, 'x', digits=2)}   aggregate "
                      f"{fmt(sum(known) if known else None, ' ev/s', digits=0)}")
-    # partitioned-mesh shards (parallel.sharded.PartitionedAggregator):
+    # partitioned-mesh shards (parallel.PartitionedAggregator):
     # one row per (member, device) off the heatmap_mesh_* families —
     # owned-cell share (this shard's rows over its member's total, the
     # PR 7 imbalance math per device), ring depth, device->host pulls,
